@@ -517,6 +517,12 @@ def _assemble_chunks(
     return out.astype(out_dtype)
 
 
+def _use_pallas_default() -> bool:
+    """Kernel choice by platform: the Pallas kernels on accelerators, the
+    XLA-fused jnp twins on CPU (where Pallas only runs interpreted)."""
+    return jax.default_backend() != "cpu"
+
+
 def decode_chunks(
     blobs: Sequence[bytes],
     ct: CodecTables,
@@ -558,7 +564,7 @@ def decode_chunks(
             f"(L={ct.n_layers}, C={ct.n_channels})"
         )
     if use_pallas is None:
-        use_pallas = jax.default_backend() != "cpu"
+        use_pallas = _use_pallas_default()
     interpret = jax.default_backend() == "cpu"
 
     metas = []

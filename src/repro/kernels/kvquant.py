@@ -13,6 +13,14 @@ Layout: the chunk's tokens are *grouped* (group_size g): deltas are
 anchor[i, :].  Grid = (L2, G/Bg); each block holds Bg whole groups with the
 full channel width so the anchor broadcast never crosses blocks.
 
+TPU tiling (token kernels): Mosaic wants a block's last two dimensions to
+be multiples of (8, 128) or whole.  The group axis G (26 for 256-token
+chunks in groups of 10) is neither, so per-group operands carry singleton
+axes that make their last two dimensions whole: anchors ``(B, G, 1, C)``,
+per-group scales ``(B, G, 1, 1)``, per-row bins ``(B, 1, 1)``.  uint16
+symbols widen through int32 (Mosaic has no uint16 -> f32 cast).
+``tests/test_chip_compile.py`` compiles both token kernels for a v5e.
+
 Fused-path / oracle split (PR 1): these kernels are the *production* decode
 path — ``core/codec.decode_chunks`` feeds them whole batches of chunks (the
 leading axis folds n_chunks × L × 2) and they emit full token blocks
@@ -102,10 +110,11 @@ def kv_dequant_pallas(
 
 
 def _dequant_tokens_kernel(d_sym_ref, anchors_ref, bins_ref, out_ref, *, qmax: int):
-    # d_sym: (1, Bg, g-1, C) | anchors: (1, Bg, C) f32 | out: (1, Bg, g, C)
-    d = d_sym_ref[0].astype(jnp.float32) - float(qmax)
-    b = bins_ref[0, 0]
-    anchor = anchors_ref[0][:, None, :]  # (Bg, 1, C)
+    # d_sym: (1, Bg, g-1, C) | anchors: (1, Bg, 1, C) f32 | bins: (1, 1, 1)
+    # out: (1, Bg, g, C)
+    d = d_sym_ref[0].astype(jnp.int32).astype(jnp.float32) - float(qmax)
+    b = bins_ref[0]  # (1, 1)
+    anchor = anchors_ref[0]  # (Bg, 1, C)
     tokens = jnp.concatenate([anchor, d * b + anchor], axis=1)  # (Bg, g, C)
     out_ref[0] = tokens.astype(out_ref.dtype)
 
@@ -138,25 +147,29 @@ def kv_dequant_tokens_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Bg, gm1, C), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, Bg, C), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, Bg, 1, C), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Bg, gm1 + 1, C), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, G, gm1 + 1, C), out_dtype),
         interpret=interpret,
-    )(d_sym, anchors, bins.reshape(B, 1).astype(jnp.float32))
+    )(
+        d_sym,
+        anchors.reshape(B, G, 1, C),
+        bins.reshape(B, 1, 1).astype(jnp.float32),
+    )
 
 
 def _lossless_tokens_kernel(d_sym_ref, a_sym_ref, scales_ref, out_ref):
     # d_sym: (1, Bg, g-1, C) uint16 integer-delta symbols (bias 254)
-    # a_sym: (1, Bg, C) uint16 8-bit anchor symbols (bias 128)
-    # scales: (1, Bg) f32 per-group shared scale
-    q_a = a_sym_ref[0].astype(jnp.float32) - 128.0  # (Bg, C)
-    q_d = d_sym_ref[0].astype(jnp.float32) - 254.0  # (Bg, g-1, C)
-    s = scales_ref[0][:, None]  # (Bg, 1)
-    anchor = q_a * s  # (Bg, C)
-    others = (q_d + q_a[:, None, :]) * s[..., None]  # (Bg, g-1, C)
-    tokens = jnp.concatenate([anchor[:, None, :], others], axis=1)
+    # a_sym: (1, Bg, 1, C) uint16 8-bit anchor symbols (bias 128)
+    # scales: (1, Bg, 1, 1) f32 per-group shared scale
+    q_a = a_sym_ref[0].astype(jnp.int32).astype(jnp.float32) - 128.0  # (Bg, 1, C)
+    q_d = d_sym_ref[0].astype(jnp.int32).astype(jnp.float32) - 254.0  # (Bg, g-1, C)
+    s = scales_ref[0]  # (Bg, 1, 1)
+    anchor = q_a * s  # (Bg, 1, C)
+    others = (q_d + q_a) * s  # (Bg, g-1, C)
+    tokens = jnp.concatenate([anchor, others], axis=1)
     out_ref[0] = tokens.astype(out_ref.dtype)
 
 
@@ -186,13 +199,17 @@ def kv_lossless_tokens_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Bg, gm1, C), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, Bg, C), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, Bg), lambda i, j: (i, j)),
+            pl.BlockSpec((1, Bg, 1, C), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, Bg, 1, 1), lambda i, j: (i, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Bg, gm1 + 1, C), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, G, gm1 + 1, C), out_dtype),
         interpret=interpret,
-    )(d_sym, a_sym, scales.astype(jnp.float32))
+    )(
+        d_sym,
+        a_sym.reshape(B, G, 1, C),
+        scales.reshape(B, G, 1, 1).astype(jnp.float32),
+    )
 
 
 def _quant_kernel(kv_ref, bins_ref, sym_ref, *, qmax: int, gm1: int):

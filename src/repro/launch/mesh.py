@@ -54,13 +54,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
     devices = _require_devices(n, shape, axes)
-    try:
-        return jax.make_mesh(shape, axes, devices=devices)
-    except TypeError:  # older signature without devices kwarg
-        if len(jax.devices()) == n:
-            return jax.make_mesh(shape, axes)
-        arr = np.asarray(devices).reshape(shape)
-        return Mesh(arr, axes)
+    return jax.make_mesh(shape, axes, devices=devices)
 
 
 def make_test_mesh(data: int = 2, model: int = 2) -> Mesh:

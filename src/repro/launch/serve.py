@@ -1,6 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Brings up the engine for a (reduced) architecture, stores a context pool
+Brings up the engine for an architecture at its published widths
+(``--arch smollm-360m``; a ``-tiny`` suffix selects the reduced
+same-family config, e.g. ``--arch smollm-360m-tiny`` on a CPU), stores a
+context pool
 through the CacheGen streamer, then serves a request loop — each request is
 a live closed-loop :class:`~repro.serving.session.ServeSession`: per chunk
 it measures realized throughput from the trace-driven fetch, picks the next
@@ -87,8 +90,94 @@ is load-only and bit-identical to the PR 8 open-loop path.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Any, Callable, Optional
 
 import numpy as np
+
+SERVED_FAMILIES = ("dense", "moe", "vlm")
+
+
+@dataclasses.dataclass
+class ServedContext:
+    """One stored context: its tokens, its prefill output and its store."""
+
+    context_id: str
+    tokens: np.ndarray  # (1, T) context token ids
+    logits: Any  # (1, 1, V) last-position prefill logits
+    store: Any  # KVStore (or TieredKVStore) holding every level
+    streamer: Any  # CacheGenStreamer over ``store``
+
+    @property
+    def first_token(self) -> int:
+        """Greedy first output token: the prefill's TTFT artifact."""
+        import jax.numpy as jnp
+
+        return int(jnp.argmax(self.logits[0, -1]))
+
+
+def build_engine(cfg, *, capacity: int, seed: int = 0):
+    """Serving engine over random weights drawn from ``seed``."""
+    import jax
+
+    from repro.models import build
+    from repro.serving.engine import Engine
+
+    if cfg.family not in SERVED_FAMILIES:
+        raise SystemExit(
+            f"arch {cfg.name} is a {cfg.family!r} model; the serving path "
+            f"streams KV caches of the attention families "
+            f"{', '.join(SERVED_FAMILIES)}"
+        )
+    params = jax.jit(build(cfg).init_params)(jax.random.PRNGKey(seed))
+    return Engine(cfg, params, cache_capacity=capacity)
+
+
+def load_context(
+    engine,
+    *,
+    ctx_len: int,
+    chunk_tokens: int,
+    seed: int = 0,
+    make_store: Optional[Callable] = None,
+) -> ServedContext:
+    """Prefill a MarkovLM context drawn from ``seed``, profile the codec
+    tables on its KV, and store every encoding level as context ``"ctx"``
+    in ``chunk_tokens``-token chunks.  ``make_store(tables)`` builds the
+    store (default: the flat ``KVStore``).
+    """
+    import jax.numpy as jnp
+
+    from repro.core import codec as kvcodec
+    from repro.data import MarkovLM
+    from repro.serving.kv_layout import caches_to_codec_kv
+    from repro.streaming import CacheGenStreamer, KVStore
+
+    cfg = engine.cfg
+    context_id = "ctx"
+    rng = np.random.default_rng(seed)
+    tokens = MarkovLM(vocab_size=cfg.vocab_size, seed=seed).sample(rng, ctx_len)[None]
+    batch = {"tokens": jnp.asarray(tokens)}
+    if cfg.family == "vlm":
+        batch["patch_embeds"] = jnp.asarray(
+            rng.normal(size=(1, cfg.n_prefix_tokens, cfg.frontend_dim)),
+            jnp.float32,
+        )
+    logits, caches = engine.calculate_kv(batch)
+    n_cached = ctx_len + (cfg.n_prefix_tokens if cfg.family == "vlm" else 0)
+    kv = caches_to_codec_kv(caches, 0, n_cached)
+    tables = kvcodec.profile([kv], kvcodec.CodecConfig(precision=11))
+    store = (make_store or KVStore)(tables)
+    store.store_kv(
+        context_id, kv, chunk_tokens=chunk_tokens,
+        # canonical token-chain hashing when the KV rows are 1:1 with
+        # text tokens; a vlm's prefix rows aren't, so hash KV bytes there
+        tokens=tokens[0].tolist() if tokens.shape[1] == n_cached else None,
+    )
+    return ServedContext(
+        context_id=context_id, tokens=tokens, logits=logits,
+        store=store, streamer=CacheGenStreamer(store, cfg),
+    )
 
 
 def _parse_arrivals(spec: str, n: int, seed: int):
@@ -121,7 +210,9 @@ def _parse_arrivals(spec: str, n: int, seed: int):
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m")
+    ap.add_argument("--arch", default="smollm-360m",
+                    help="registry name at published widths; append -tiny "
+                         "for the reduced same-family config")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--ctx-len", type=int, default=300)
     ap.add_argument("--slo-ms", type=float, default=250)
@@ -249,67 +340,34 @@ def main() -> None:
             "via --gen"
         )
 
-    import jax
     import jax.numpy as jnp
 
     from repro.configs import registry
-    from repro.core import codec as kvcodec
-    from repro.data import MarkovLM
-    from repro.models import build
-    from repro.serving.engine import Engine
-    from repro.serving.kv_layout import caches_to_codec_kv
+    from repro.launch.compile_cache import enable_compilation_cache
     from repro.serving.session import ServeSession
-    from repro.streaming import (
-        BandwidthTrace,
-        CacheGenStreamer,
-        KVStore,
-        NetworkModel,
-    )
+    from repro.streaming import BandwidthTrace, NetworkModel
     from repro.streaming.adaptation import TEXT
 
-    cfg = registry.get(args.arch).tiny()
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise SystemExit(
-            f"--arch {args.arch}: serve driver supports attention families "
-            "(KV-cache streaming); see DESIGN.md §Arch-applicability"
-        )
-    model = build(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    engine = Engine(cfg, params, cache_capacity=args.ctx_len + 32 + args.generate)
-    lm = MarkovLM(vocab_size=cfg.vocab_size, seed=0)
-    rng = np.random.default_rng(0)
-    tokens = lm.sample(rng, args.ctx_len)[None]
-    if cfg.family == "vlm":
-        batch = {
-            "tokens": jnp.asarray(tokens),
-            "patch_embeds": jnp.asarray(
-                rng.normal(size=(1, cfg.n_prefix_tokens, cfg.frontend_dim)),
-                jnp.float32,
-            ),
-        }
-    else:
-        batch = {"tokens": jnp.asarray(tokens)}
-    logits, caches = engine.calculate_kv(batch)
-    n_cached = args.ctx_len + (cfg.n_prefix_tokens if cfg.family == "vlm" else 0)
-    kv = caches_to_codec_kv(caches, 0, n_cached)
-    tables = kvcodec.profile([kv], kvcodec.CodecConfig(precision=11))
+    enable_compilation_cache()
+    cfg = registry.get(args.arch)
+    engine = build_engine(cfg, capacity=args.ctx_len + 32 + args.generate)
+    make_store = None
     if args.store == "tiered":
         from repro.streaming import DirectoryBackend, TieredKVStore
 
-        store = TieredKVStore(
-            tables,
-            hot_bytes=args.hot_bytes,
-            cold=DirectoryBackend(args.store_dir) if args.store_dir else None,
-        )
-    else:
-        store = KVStore(tables)
-    streamer = CacheGenStreamer(store, cfg)
-    store.store_kv(
-        "ctx", kv, chunk_tokens=max(args.ctx_len // 4, 50),
-        # canonical token-chain hashing when the KV rows are 1:1 with
-        # text tokens; a vlm's prefix rows aren't, so hash KV bytes there
-        tokens=tokens[0].tolist() if tokens.shape[1] == n_cached else None,
+        def make_store(tables):
+            return TieredKVStore(
+                tables,
+                hot_bytes=args.hot_bytes,
+                cold=DirectoryBackend(args.store_dir) if args.store_dir else None,
+            )
+
+    ctx = load_context(
+        engine, ctx_len=args.ctx_len, chunk_tokens=max(args.ctx_len // 4, 50),
+        make_store=make_store,
     )
+    store, streamer, tokens, logits = ctx.store, ctx.streamer, ctx.tokens, ctx.logits
+    rng = np.random.default_rng(0)  # link traces
     print(f"[serve] context stored: {store.storage_bytes('ctx')/1e3:.1f} KB all levels")
 
     # fetch path: sim (default, per-request trace pacing), local, or a real

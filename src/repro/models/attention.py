@@ -229,9 +229,7 @@ def _decode_mha_sp(q, kc, vc, kv_len, mesh, seq_axis: str):
         o = acc_glob / jnp.maximum(l_glob[..., 0][..., None], 1e-30)
         return o.reshape(B, Hq, D).astype(q.dtype)
 
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -241,7 +239,7 @@ def _decode_mha_sp(q, kc, vc, kv_len, mesh, seq_axis: str):
             P(batch_axes),
         ),
         out_specs=P(batch_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, kc, vc, kv_len)
 
 

@@ -213,7 +213,6 @@ def _grouped_ffn_combine_sm(
 ):
     """shard_map expert FFN: ff sharded over ``mlp_axis``, groups over dp;
     partial down-proj outputs are combined locally, then psum'd once."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     G, E, capacity, d = buf.shape
@@ -235,7 +234,7 @@ def _grouped_ffn_combine_sm(
         )
         return jax.lax.psum(out, mlp_axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -249,5 +248,5 @@ def _grouped_ffn_combine_sm(
             P(dp, None),
         ),
         out_specs=P(dp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(buf, p["w_gate"], p["w_up"], p["w_down"], slot, sorted_t, sorted_w, keep)

@@ -99,8 +99,9 @@ class Engine:
             self._decode_rows = None
         # Decoded-run insertion: donate the cache buffers so XLA performs an
         # in-place dynamic_update_slice instead of copying the whole cache
-        # per insertion (donation is a no-op hint on CPU, where XLA warns).
-        donate = () if jax.default_backend() == "cpu" else (0, 1)
+        # per insertion.  Donation holds on every backend, so a caller that
+        # reads a cache after passing it in fails the same way everywhere.
+        donate = (0, 1)
         self._insert_run = jax.jit(kv_layout.insert_codec_run, donate_argnums=donate)
         self._insert_runs = jax.jit(
             kv_layout.insert_codec_runs,
@@ -115,7 +116,7 @@ class Engine:
             # gather -> compact prefill_extend -> scatter back: coalesced
             # TEXT recompute that only computes the participating rows
             # (cache buffers donated so the row scatter updates in place)
-            gather_donate = () if jax.default_backend() == "cpu" else (2, 3)
+            gather_donate = (2, 3)
 
             def _extend_gather_impl(params, tokens, kv_k, kv_v, length, rows):
                 sub = lm.Caches(

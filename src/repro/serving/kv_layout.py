@@ -346,17 +346,26 @@ def reset_rows(
 
 
 def extract_row(caches: Caches, row: int) -> Caches:
-    """One request's batch-1 view of a batch-of-requests cache (device
-    slices; no copy forced)."""
+    """One request's batch-1 copy of a batch-of-requests cache.
+
+    The copy is forced: slicing the only row of a one-row cache returns the
+    pool's own buffer, which the next donated update of the pool deletes.
+    """
     sl = slice(row, row + 1)
+
+    def own(x, batch_axis=1):
+        if x is None:
+            return None
+        return jnp.copy(x[sl] if batch_axis == 0 else x[:, sl])
+
     return caches._replace(
-        kv_k=None if caches.kv_k is None else caches.kv_k[:, sl],
-        kv_v=None if caches.kv_v is None else caches.kv_v[:, sl],
-        length=None if caches.length is None else caches.length[sl],
-        mamba_conv=None if caches.mamba_conv is None else caches.mamba_conv[:, sl],
-        mamba_ssm=None if caches.mamba_ssm is None else caches.mamba_ssm[:, sl],
-        shared_k=None if caches.shared_k is None else caches.shared_k[:, sl],
-        shared_v=None if caches.shared_v is None else caches.shared_v[:, sl],
+        kv_k=own(caches.kv_k),
+        kv_v=own(caches.kv_v),
+        length=own(caches.length, batch_axis=0),
+        mamba_conv=own(caches.mamba_conv),
+        mamba_ssm=own(caches.mamba_ssm),
+        shared_k=own(caches.shared_k),
+        shared_v=own(caches.shared_v),
     )
 
 

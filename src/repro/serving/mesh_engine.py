@@ -40,7 +40,6 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -109,9 +108,9 @@ class ShardedEngine(Engine):
         rep = P()
 
         def _sm(body, in_specs, out_specs):
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
 
         # --- decode_step_rows: stacked generation step per shard ---------

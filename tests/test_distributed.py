@@ -106,7 +106,6 @@ def test_compressed_psum_means_correctly():
 import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.launch.mesh import make_test_mesh
 from repro.training.grad_compress import compressed_psum
 
@@ -117,7 +116,7 @@ x = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
 def f(x):
     return compressed_psum(x, "data")
 
-y = shard_map(f, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))(x)
+y = jax.shard_map(f, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))(x)
 # exact mean over the data axis
 ref = jnp.broadcast_to(x.reshape(4, 2, 16).mean(axis=0, keepdims=True), (4, 2, 16)).reshape(8, 16)
 err = float(jnp.max(jnp.abs(y - ref)))

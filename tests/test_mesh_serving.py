@@ -452,3 +452,21 @@ def test_mesh2_suspend_resume_crosses_shards_bit_exact(mfix):
     assert {r // rows_per_shard for r in victim.rows_used} == {0, 1}
     assert out.sessions[2].ttft_s < 0.6  # the preemptor met its SLO
     assert all(s.status == "ok" for s in out.sessions)
+
+
+@needs(4)
+def test_chip_smoke_mesh_phase_tiny():
+    """``chip_smoke.py --chips 4``'s comparison at the tiny config on host
+    devices: rows spread over four shards, and every request's tokens and
+    cache equal the plain engine's bit for bit."""
+    import chip_smoke
+    from repro.configs import registry
+
+    out = chip_smoke.mesh_phase(
+        registry.get("smollm-360m-tiny"), n_chips=4, ctx_len=100,
+        chunk_tokens=CHUNK, capacity=128, gen_tokens=4,
+    )
+    assert "cache rows spread over 4 devices" in out["checks"]
+    assert "requests served on all 4 row shards" in out["checks"]
+    for i in range(8):
+        assert f"req {i} sharded cache equals plain bit for bit" in out["checks"]
