@@ -230,8 +230,6 @@ def run(
                     "levels": {str(k): v for k, v in sorted(res.level_histogram().items())},
                     "total_bytes": res.total_bytes,
                     "n_runs": res.n_runs,
-                    "wall_decode_s": res.wall_decode_s,
-                    "wall_recompute_s": res.wall_recompute_s,
                     "wall_total_s": res.wall_total_s,
                     "logit_drift_max": drift_max,
                     "logit_drift_mean": drift_mean,

@@ -47,6 +47,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import bitstream, gop, quant, rans, tables
 
@@ -552,78 +553,83 @@ def decode_chunks(
     if not blobs:
         raise ValueError("decode_chunks needs at least one blob")
     cfg = ct.config
-    parsed = [bitstream.unpack(b) for b in blobs]
-    h0 = parsed[0][0]
-    L, C, g = int(h0["n_layers"]), int(h0["n_channels"]), int(h0["group_size"])
-    for h, _ in parsed:
-        if (int(h["n_layers"]), int(h["n_channels"]), int(h["group_size"])) != (L, C, g):
-            raise ValueError("decode_chunks requires chunks with a common geometry")
-    if L != ct.n_layers or C != ct.n_channels:
-        raise ValueError(
-            f"chunk geometry (L={L}, C={C}) does not match profiled tables "
-            f"(L={ct.n_layers}, C={ct.n_channels})"
-        )
+    with TraceAnnotation("codec.parse", n_chunks=len(blobs)):
+        parsed = [bitstream.unpack(b) for b in blobs]
+        h0 = parsed[0][0]
+        L, C, g = int(h0["n_layers"]), int(h0["n_channels"]), int(h0["group_size"])
+        for h, _ in parsed:
+            geom = (int(h["n_layers"]), int(h["n_channels"]), int(h["group_size"]))
+            if geom != (L, C, g):
+                raise ValueError("decode_chunks requires chunks with a common geometry")
+        if L != ct.n_layers or C != ct.n_channels:
+            raise ValueError(
+                f"chunk geometry (L={L}, C={C}) does not match profiled tables "
+                f"(L={ct.n_layers}, C={ct.n_channels})"
+            )
+        metas = []
+        for h, _ in parsed:
+            lvl, T = int(h["level"]), int(h["n_tokens"])
+            layout = gop.make_layout(T, g)
+            metas.append((lvl, T, layout.n_anchors, layout.n_deltas))
     if use_pallas is None:
         use_pallas = _use_pallas_default()
     interpret = jax.default_backend() == "cpu"
 
-    metas = []
-    for h, _ in parsed:
-        lvl, T = int(h["level"]), int(h["n_tokens"])
-        layout = gop.make_layout(T, g)
-        metas.append((lvl, T, layout.n_anchors, layout.n_deltas))
     N = len(metas)
     n_lanes = L * 2 * C
     Gmax = max(m[2] for m in metas)
     t_idx_np = np.asarray(ct.table_idx)
     n_ta = ct.anchor.n_tables
 
-    # --- anchors: one scan over all chunks (lossy + lossless tables stacked)
-    aw, an, ax = _stack_streams(parsed, range(N), "a")
-    t_idx_a = np.concatenate(
-        [t_idx_np + (n_ta if m[0] == 0 else 0) for m in metas]
-    )
-    a_sym = rans.decode(aw, an, ax, t_idx_a, _anchor_stack(ct), Gmax)
-
-    # --- deltas: ONE scan for all chunks — lossy levels and the lossless
-    # family (different alphabet) share it via alphabet-padded table stacking
-    d_max = max(m[3] for m in metas)
-    if d_max > 0:
-        dw, dn, dx = _stack_streams(parsed, range(N), "d")
-        t_idx_d = np.concatenate(
-            [t_idx_np + _delta_table_base(ct, m[0]) for m in metas]
+    with TraceAnnotation("codec.dispatch", n_chunks=N):
+        # --- anchors: one scan over all chunks (lossy + lossless tables
+        # stacked)
+        aw, an, ax = _stack_streams(parsed, range(N), "a")
+        t_idx_a = np.concatenate(
+            [t_idx_np + (n_ta if m[0] == 0 else 0) for m in metas]
         )
-        d_sym = rans.decode(dw, dn, dx, t_idx_d, _delta_decode_stack(ct), d_max)
-    else:
-        d_sym = jnp.zeros((N * n_lanes, 0), jnp.uint16)
+        a_sym = rans.decode(aw, an, ax, t_idx_a, _anchor_stack(ct), Gmax)
 
-    # --- per-chunk side data, padded + stacked once on the host
-    lossy_idx = [i for i, m in enumerate(metas) if m[0] != 0]
-    scales = np.zeros((N, L, 2, Gmax), np.float32)
-    for i, (_, arrays) in enumerate(parsed):
-        s = arrays["scales"].astype(np.float32)
-        scales[i, :, :, : s.shape[2]] = s
-    bins = np.zeros((len(lossy_idx), L, 2), np.float32)
-    for j, i in enumerate(lossy_idx):
-        bins[j] = _bins_for_level(cfg, L, metas[i][0], ct.delta_scale)
+        # --- deltas: ONE scan for all chunks — lossy levels and the
+        # lossless family (different alphabet) share it via alphabet-padded
+        # table stacking
+        d_max = max(m[3] for m in metas)
+        if d_max > 0:
+            dw, dn, dx = _stack_streams(parsed, range(N), "d")
+            t_idx_d = np.concatenate(
+                [t_idx_np + _delta_table_base(ct, m[0]) for m in metas]
+            )
+            d_sym = rans.decode(dw, dn, dx, t_idx_d, _delta_decode_stack(ct), d_max)
+        else:
+            d_sym = jnp.zeros((N * n_lanes, 0), jnp.uint16)
 
-    # static meta carries geometry + the binary lossy/lossless partition
-    # only; the chosen lossy level reaches the trace as data (bins)
-    shape_meta = (
-        L, C, g, cfg.delta_qmax,
-        tuple((T, G, D, lvl == 0) for (lvl, T, G, D) in metas),
-    )
-    return _assemble_chunks(
-        a_sym,
-        d_sym,
-        jnp.asarray(scales),
-        jnp.asarray(bins),
-        shape_meta=shape_meta,
-        out_dtype=np.dtype(out_dtype),
-        use_pallas=bool(use_pallas),
-        interpret=interpret,
-        block_groups=block_groups,
-    )
+        # --- per-chunk side data, padded + stacked once on the host
+        lossy_idx = [i for i, m in enumerate(metas) if m[0] != 0]
+        scales = np.zeros((N, L, 2, Gmax), np.float32)
+        for i, (_, arrays) in enumerate(parsed):
+            s = arrays["scales"].astype(np.float32)
+            scales[i, :, :, : s.shape[2]] = s
+        bins = np.zeros((len(lossy_idx), L, 2), np.float32)
+        for j, i in enumerate(lossy_idx):
+            bins[j] = _bins_for_level(cfg, L, metas[i][0], ct.delta_scale)
+
+        # static meta carries geometry + the binary lossy/lossless partition
+        # only; the chosen lossy level reaches the trace as data (bins)
+        shape_meta = (
+            L, C, g, cfg.delta_qmax,
+            tuple((T, G, D, lvl == 0) for (lvl, T, G, D) in metas),
+        )
+        return _assemble_chunks(
+            a_sym,
+            d_sym,
+            jnp.asarray(scales),
+            jnp.asarray(bins),
+            shape_meta=shape_meta,
+            out_dtype=np.dtype(out_dtype),
+            use_pallas=bool(use_pallas),
+            interpret=interpret,
+            block_groups=block_groups,
+        )
 
 
 def decode_chunk_runs(

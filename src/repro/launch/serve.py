@@ -528,7 +528,7 @@ def main() -> None:
         print(
             f"[req {r}] configs={[names.get(c, f'L{c}') for c in res.configs]} "
             f"ttft={res.ttft_s*1e3:.1f} ms ok={not res.slo_violated} "
-            f"runs={res.n_runs} wall_decode={res.wall_decode_s*1e3:.1f} ms "
+            f"runs={res.n_runs} "
             f"tokens={gen[0].tolist()}" + hedge + fault + extra
         )
 
@@ -591,9 +591,15 @@ def main() -> None:
                 + (f" preempted={tl.n_preemptions}x" if tl.n_preemptions else "")
             )
             if tl.n_tokens_out:
+                # host clock: run start to the first token's logits on the
+                # host, and the mean gap between the token syncs
+                wall = tl.token_wall
+                gap = (wall[-1] - wall[0]) / max(len(wall) - 1, 1)
                 extra += (
                     f" gen={tl.n_tokens_out}tok"
                     f" tpot_mean={tl.mean_tpot_s*1e3:.2f}ms"
+                    f" wall_ttft={(wall[0] - tl.start_wall)*1e3:.1f}ms"
+                    f" wall_gap={gap*1e3:.2f}ms"
                 )
             describe(r, res, extra)
         ttfts = sorted(s.ttft_s for s in out.sessions)

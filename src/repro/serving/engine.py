@@ -33,6 +33,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import lm
@@ -256,12 +257,13 @@ class Engine:
                     f"run of {t} tokens at offset {s} overhangs cache "
                     f"capacity {self.capacity}"
                 )
-        k, v, ln = self._insert_runs(
-            caches.kv_k, caches.kv_v, caches.length, jnp.asarray(kv_new),
-            jnp.asarray(list(rows), jnp.int32),
-            jnp.asarray(list(starts), jnp.int32),
-            run_tokens=tuple(int(t) for t in run_tokens),
-        )
+        with TraceAnnotation("engine.insert_runs", n_runs=len(rows)):
+            k, v, ln = self._insert_runs(
+                caches.kv_k, caches.kv_v, caches.length, jnp.asarray(kv_new),
+                jnp.asarray(list(rows), jnp.int32),
+                jnp.asarray(list(starts), jnp.int32),
+                run_tokens=tuple(int(t) for t in run_tokens),
+            )
         return caches._replace(kv_k=k, kv_v=v, length=ln)
 
     # ------------------------------------------------------------------

@@ -150,6 +150,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import codec as kvcodec
 from repro.models.lm import Caches
@@ -213,17 +214,14 @@ class SchedulerResult:
 
     ``sessions[r].caches`` is request ``r``'s batch-1 view of the shared
     batch-of-requests cache (``caches`` holds the full batch).  Virtual
-    times (``ttft_s``) are per-request and contention-aware; ``wall_*`` on
-    the scheduler are realized host seconds for the whole batch run, and
-    each session's ``wall_*`` is its token-weighted share of the batched
-    dispatches it participated in.
+    times (``ttft_s``) are per-request and contention-aware;
+    ``wall_total_s`` is realized host seconds for the whole batch run,
+    through a final device sync.
     """
 
     sessions: List[SessionResult]
     caches: Caches
     wall_total_s: float
-    wall_decode_s: float
-    wall_recompute_s: float
     n_rounds: int
     n_decode_batches: int
     n_text_batches: int
@@ -243,17 +241,13 @@ class SchedulerResult:
 
 @dataclasses.dataclass
 class _SessionAccount:
-    """Per-session share of the batched dispatch times."""
+    """Per-session count of the batched decodes it took part in."""
 
-    decode_s: float = 0.0
-    recompute_s: float = 0.0
     runs: int = 0
 
 
 @dataclasses.dataclass
 class _BatchStats:
-    decode_s: float = 0.0
-    recompute_s: float = 0.0
     gen_s: float = 0.0  # wall seconds in stacked generation steps
     n_rounds: int = 0
     n_decode_batches: int = 0
@@ -277,7 +271,6 @@ def _execute_runs(
     for w in runs:
         groups.setdefault(id(w.tables), []).append(w)
     for group in groups.values():
-        t0 = time.perf_counter()
         # token counts come from the plan (validated against every
         # fetched blob's header at fetch time); decode_chunk_runs
         # cross-checks the decoded total against them
@@ -294,13 +287,9 @@ def _execute_runs(
             starts=[w.start for w in group],
             run_tokens=[n for _, n in spans],
         )
-        dt = time.perf_counter() - t0
-        stats.decode_s += dt
         stats.n_decode_batches += 1
         stats.n_runs += len(group)
-        total = sum(w.n_tokens for w in group)
         for w in group:
-            acct_by_row[w.row].decode_s += dt * w.n_tokens / total
             acct_by_row[w.row].runs += 1
     return caches
 
@@ -309,7 +298,6 @@ def _execute_texts(
     engine: Engine,
     texts: List[TextWork],
     caches: Caches,
-    acct_by_row: Mapping[int, _SessionAccount],
     stats: _BatchStats,
 ) -> Caches:
     """Coalesced TEXT recompute: one padded masked forward per chunk width
@@ -322,38 +310,31 @@ def _execute_texts(
     for w in texts:
         by_tc.setdefault(w.n_tokens, []).append(w)
     for tc, group in sorted(by_tc.items()):
-        t0 = time.perf_counter()
-        if 2 * len(group) >= n:
-            # most (or all) rows recompute: width-masked full-batch
-            # forward — non-participating rows ride along with width 0,
-            # no gather/scatter traffic
-            toks = np.zeros((n, tc), np.int32)
-            widths = np.zeros((n,), np.int32)
-            for w in group:
-                toks[w.row] = np.asarray(w.tokens[0], np.int32)
-                widths[w.row] = tc
-            _, caches = engine.prefill_extend_rows(
-                jnp.asarray(toks), caches, widths
-            )
-        else:
-            # a small subset: gather the participating rows into a
-            # compact sub-batch so compute scales with them, not the
-            # full batch
-            toks = np.stack(
-                [np.asarray(w.tokens[0], np.int32) for w in group]
-            )
-            _, caches = engine.prefill_extend_gather(
-                jnp.asarray(toks), caches, [w.row for w in group]
-            )
-        dt = time.perf_counter() - t0
-        stats.recompute_s += dt
+        with TraceAnnotation("engine.recompute", n_rows=len(group),
+                             tokens=tc * len(group)):
+            if 2 * len(group) >= n:
+                # most (or all) rows recompute: width-masked full-batch
+                # forward — non-participating rows ride along with width 0,
+                # no gather/scatter traffic
+                toks = np.zeros((n, tc), np.int32)
+                widths = np.zeros((n,), np.int32)
+                for w in group:
+                    toks[w.row] = np.asarray(w.tokens[0], np.int32)
+                    widths[w.row] = tc
+                _, caches = engine.prefill_extend_rows(
+                    jnp.asarray(toks), caches, widths
+                )
+            else:
+                # a small subset: gather the participating rows into a
+                # compact sub-batch so compute scales with them, not the
+                # full batch
+                toks = np.stack(
+                    [np.asarray(w.tokens[0], np.int32) for w in group]
+                )
+                _, caches = engine.prefill_extend_gather(
+                    jnp.asarray(toks), caches, [w.row for w in group]
+                )
         stats.n_text_batches += 1
-        # token-weighted share, mirroring the decode accounting (groups are
-        # same-width today, so this equals an even split — but the share
-        # rule must not silently change if grouping ever mixes widths)
-        total = sum(w.n_tokens for w in group)
-        for w in group:
-            acct_by_row[w.row].recompute_s += dt * w.n_tokens / total
     return caches
 
 
@@ -495,15 +476,13 @@ class ConcurrentScheduler:
             # drain: decodes/inserts land before recomputes — a task emits
             # at most [run, text] per round, so this preserves its order
             caches = _execute_runs(self.engine, round_runs, caches, acct_by_row, stats)
-            caches = _execute_texts(self.engine, round_texts, caches, acct_by_row, stats)
+            caches = _execute_texts(self.engine, round_texts, caches, stats)
         jax.block_until_ready(caches.kv_k)
         wall_total = time.perf_counter() - wall0
 
         sessions = [
             t.result(
                 extract_row(caches, i),
-                wall_decode_s=acct[i].decode_s,
-                wall_recompute_s=acct[i].recompute_s,
                 wall_total_s=wall_total,
                 n_runs=acct[i].runs,
             )
@@ -513,8 +492,6 @@ class ConcurrentScheduler:
             sessions=sessions,
             caches=caches,
             wall_total_s=wall_total,
-            wall_decode_s=stats.decode_s,
-            wall_recompute_s=stats.recompute_s,
             n_rounds=stats.n_rounds,
             n_decode_batches=stats.n_decode_batches,
             n_text_batches=stats.n_text_batches,
@@ -780,6 +757,11 @@ class RequestTimeline:
     ``gen_slo_miss`` counts emitted tokens whose realized TPOT exceeded the
     request's ``GenerationSpec.gen_slo_s`` (0 when no per-token SLO was
     set).
+
+    ``start_wall`` and ``token_wall`` are the host clock
+    (``time.perf_counter()``): when the scheduler's ``run`` started, and
+    when each emitted token's logits reached the host (one entry per
+    ``tokens_out`` entry).
     """
 
     index: int
@@ -793,6 +775,8 @@ class RequestTimeline:
     token_ts: List[float] = dataclasses.field(default_factory=list)
     gen_finish_t: float = float("nan")
     gen_slo_miss: int = 0
+    start_wall: float = float("nan")
+    token_wall: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def queue_wait_s(self) -> float:
@@ -842,8 +826,6 @@ class ContinuousResult:
     occupancy: List[Tuple[float, int]]
     n_rows: int
     wall_total_s: float
-    wall_decode_s: float
-    wall_recompute_s: float
     n_rounds: int
     n_decode_batches: int
     n_text_batches: int
@@ -946,6 +928,10 @@ class ContinuousScheduler:
     # ------------------------------------------------------------------
 
     def run(self, requests: List[SessionRequest]) -> ContinuousResult:
+        with TraceAnnotation("sched.run", n_requests=len(requests)):
+            return self._run(requests)
+
+    def _run(self, requests: List[SessionRequest]) -> ContinuousResult:
         if not requests:
             raise ValueError("ContinuousScheduler.run needs at least one request")
         _validate_requests(self.engine, requests)
@@ -1157,9 +1143,14 @@ class ContinuousScheduler:
             at the step instant advances one token in a single
             ``decode_step_rows`` dispatch; rows mid-resume join the next
             step (continuous batching at step boundaries)."""
-            nonlocal caches, gen_busy_t
             step_t = gen_next_t()
             part = [g for g in generating if g.ready_t <= step_t]
+            with TraceAnnotation("sched.gen_step", step=stats.n_gen_steps,
+                                 rows=len(part)):
+                _gen_step(step_t, part)
+
+        def _gen_step(step_t: float, part: List[GenerationTask]) -> None:
+            nonlocal caches, gen_busy_t
             tokens = np.zeros((n_rows, 1), np.int32)
             active = np.zeros((n_rows,), bool)
             for g in part:
@@ -1170,8 +1161,10 @@ class ContinuousScheduler:
                 jnp.asarray(tokens), caches, jnp.asarray(active)
             )
             # host sync per step: the sampled tokens are the next inputs
-            last = np.asarray(logits[:, -1], np.float32)
-            dt = time.perf_counter() - t0
+            with TraceAnnotation("sched.logits_sync"):
+                last = np.asarray(logits[:, -1], np.float32)
+            t_sync = time.perf_counter()
+            dt = t_sync - t0
             m = len(part)
             # the shards step in lockstep, so the step's virtual duration is
             # the busiest shard's stacked width (== m on one shard)
@@ -1189,6 +1182,7 @@ class ContinuousScheduler:
             gen_occupancy.append((step_t, m))
             for g in part:
                 g.record(g.next_token(last[g.row]), end_t)
+                timeline[g.index].token_wall.append(t_sync)
             gen_busy_t = end_t
             for g in [x for x in part if x.done]:
                 idx = g.index
@@ -1202,90 +1196,95 @@ class ContinuousScheduler:
                 pool.release(g.row, g.label, end_t)
 
         wall0 = time.perf_counter()
+        for tl in timeline:
+            tl.start_wall = wall0
         while live or waiting or generating:
             # --- admission + preemption at the virtual frontier ------------
             if waiting:
-                if live or generating:
-                    horizons = [t.horizon_t() for t in live]
-                    if generating:
-                        horizons.append(gen_next_t())
-                    frontier = min(horizons)
-                else:
-                    # nothing live: the next admission happens at the freed
-                    # row's release instant (or the earliest arrival if the
-                    # row freed before anyone arrived), so every waiter
-                    # arrived by then is an admission candidate — EDF must
-                    # rank them all, not just the earliest arrival
-                    frontier = max(waiting[0][0], pool.next_free_since)
-                while waiting and waiting[0][0] <= frontier and pool.n_free > 0:
-                    ready_t, idx = pop_next_waiter(frontier)
-                    admit(idx, ready_t)
-                while (
-                    self.preemption is not None
-                    and waiting
-                    and pool.n_free == 0
-                    and waiting[0][0] <= frontier
-                ):
-                    policy = self.preemption
-                    head_ready, head_idx = peek_next_waiter(frontier)
-                    head_deadline = _slo_deadline(head_idx)
-                    cands: List[_VictimCandidate] = []
-                    for t in live:
-                        end = t.peek_pending_end_t()
-                        if end is None:
-                            continue
-                        # a candidate's eviction instant: when the waiter
-                        # became ready, but never before the candidate's
-                        # in-flight fetch started (the engine cannot cancel
-                        # in the past)
-                        preempt_t = max(head_ready, t.next_fetch_t)
-                        if end <= t.deadline_t + policy.margin_s:
-                            continue  # fetch lands within the SLO: keep it
-                        if (
-                            policy.require_waiting_headroom
-                            and preempt_t >= head_deadline
-                        ):
-                            continue  # waiter would start already expired
-                        cands.append(_VictimCandidate(
-                            obj=t, is_gen=False, end_t=end,
-                            preempt_t=preempt_t, work=t.realized_tokens,
-                        ))
-                    # generating rows are eligible under the cost-aware rule
-                    # (TTFT already served, residual work suspends
-                    # losslessly — no doomed-fetch test applies), and under
-                    # either rule with ``gen_slo`` once they have missed
-                    # their per-token SLO on a post-resume token
-                    for g in generating:
-                        # anti-thrash guard: a generation that has not
-                        # emitted a token since it (re)started is not
-                        # evictable — without this, two generating rows
-                        # under ``least_work`` livelock (the evicted task
-                        # re-enters as head waiter and evicts the other at
-                        # the same virtual instant, forever)
-                        if g.tokens_since_resume <= 0:
-                            continue
-                        slo_doomed = policy.gen_slo and g.slo_missed
-                        if policy.victim != "least_work" and not slo_doomed:
-                            continue
-                        preempt_t = max(head_ready, g.ready_t)
-                        if (
-                            policy.require_waiting_headroom
-                            and preempt_t >= head_deadline
-                        ):
-                            continue
-                        cands.append(_VictimCandidate(
-                            obj=g, is_gen=True, end_t=float("inf"),
-                            preempt_t=preempt_t, work=g.realized_tokens,
-                        ))
-                    victim = _select_victim(policy, cands)
-                    if victim is None:
-                        break
-                    pop_next_waiter(frontier)
-                    if victim.is_gen:
-                        preempt_gen(victim.obj, victim.preempt_t)
+                with TraceAnnotation("sched.admit", round=stats.n_rounds):
+                    if live or generating:
+                        horizons = [t.horizon_t() for t in live]
+                        if generating:
+                            horizons.append(gen_next_t())
+                        frontier = min(horizons)
                     else:
-                        preempt(victim.obj, victim.preempt_t)
-                    admit(head_idx, head_ready)
+                        # nothing live: the next admission happens at the
+                        # freed row's release instant (or the earliest arrival
+                        # if the row freed before anyone arrived), so every
+                        # waiter arrived by then is an admission candidate —
+                        # EDF must rank them all, not just the earliest arrival
+                        frontier = max(waiting[0][0], pool.next_free_since)
+                    while (
+                        waiting and waiting[0][0] <= frontier and pool.n_free > 0
+                    ):
+                        ready_t, idx = pop_next_waiter(frontier)
+                        admit(idx, ready_t)
+                    while (
+                        self.preemption is not None
+                        and waiting
+                        and pool.n_free == 0
+                        and waiting[0][0] <= frontier
+                    ):
+                        policy = self.preemption
+                        head_ready, head_idx = peek_next_waiter(frontier)
+                        head_deadline = _slo_deadline(head_idx)
+                        cands: List[_VictimCandidate] = []
+                        for t in live:
+                            end = t.peek_pending_end_t()
+                            if end is None:
+                                continue
+                            # a candidate's eviction instant: when the
+                            # waiter became ready, but never before the
+                            # candidate's in-flight fetch started (the engine
+                            # cannot cancel in the past)
+                            preempt_t = max(head_ready, t.next_fetch_t)
+                            if end <= t.deadline_t + policy.margin_s:
+                                continue  # fetch lands within the SLO: keep it
+                            if (
+                                policy.require_waiting_headroom
+                                and preempt_t >= head_deadline
+                            ):
+                                continue  # waiter would start already expired
+                            cands.append(_VictimCandidate(
+                                obj=t, is_gen=False, end_t=end,
+                                preempt_t=preempt_t, work=t.realized_tokens,
+                            ))
+                        # generating rows are eligible under the cost-aware
+                        # rule (TTFT already served, residual work suspends
+                        # losslessly — no doomed-fetch test applies), and
+                        # under either rule with ``gen_slo`` once they have
+                        # missed their per-token SLO on a post-resume token
+                        for g in generating:
+                            # anti-thrash guard: a generation that has not
+                            # emitted a token since it (re)started is not
+                            # evictable — without this, two generating rows
+                            # under ``least_work`` livelock (the evicted
+                            # task re-enters as head waiter and evicts the
+                            # other at the same virtual instant, forever)
+                            if g.tokens_since_resume <= 0:
+                                continue
+                            slo_doomed = policy.gen_slo and g.slo_missed
+                            if policy.victim != "least_work" and not slo_doomed:
+                                continue
+                            preempt_t = max(head_ready, g.ready_t)
+                            if (
+                                policy.require_waiting_headroom
+                                and preempt_t >= head_deadline
+                            ):
+                                continue
+                            cands.append(_VictimCandidate(
+                                obj=g, is_gen=True, end_t=float("inf"),
+                                preempt_t=preempt_t, work=g.realized_tokens,
+                            ))
+                        victim = _select_victim(policy, cands)
+                        if victim is None:
+                            break
+                        pop_next_waiter(frontier)
+                        if victim.is_gen:
+                            preempt_gen(victim.obj, victim.preempt_t)
+                        else:
+                            preempt(victim.obj, victim.preempt_t)
+                        admit(head_idx, head_ready)
             if not live and not generating:
                 continue  # admission above is guaranteed to make progress
 
@@ -1298,39 +1297,43 @@ class ContinuousScheduler:
 
             # --- one wave-identical round over the live set ----------------
             stats.n_rounds += 1
-            round_t = min(t.next_fetch_t for t in live)
-            ordered = sorted(live, key=lambda t: t.next_fetch_t)
-            ready = [t for t in ordered if t.fetch_ready]
-            round_runs: List[RunWork] = []
-            round_texts: List[TextWork] = []
-            for t in ready if ready else ordered[:1]:
-                self._n_active = (
-                    sum(1 for x in live if not x.done) + len(generating)
+            with TraceAnnotation("sched.round", round=stats.n_rounds,
+                                 n_live=len(live)):
+                round_t = min(t.next_fetch_t for t in live)
+                ordered = sorted(live, key=lambda t: t.next_fetch_t)
+                ready = [t for t in ordered if t.fetch_ready]
+                round_runs: List[RunWork] = []
+                round_texts: List[TextWork] = []
+                for t in ready if ready else ordered[:1]:
+                    self._n_active = (
+                        sum(1 for x in live if not x.done) + len(generating)
+                    )
+                    for w in t.step():
+                        is_run = isinstance(w, RunWork)
+                        (round_runs if is_run else round_texts).append(w)
+                caches = _execute_runs(
+                    self.engine, round_runs, caches, acct_by_row, stats
                 )
-                for w in t.step():
-                    (round_runs if isinstance(w, RunWork) else round_texts).append(w)
-            caches = _execute_runs(self.engine, round_runs, caches, acct_by_row, stats)
-            caches = _execute_texts(self.engine, round_texts, caches, acct_by_row, stats)
+                caches = _execute_texts(self.engine, round_texts, caches, stats)
 
-            # --- completions: extract the row, then generate or recycle ----
-            for t in [x for x in live if x.done]:
-                idx = row_owner[t.row]
-                finish_t = max(t.clock.fetch_t, t.clock.compute_t)
-                results[idx] = t.result(
-                    extract_row(caches, t.row),
-                    wall_decode_s=acct[idx].decode_s,
-                    wall_recompute_s=acct[idx].recompute_s,
-                    wall_total_s=0.0,  # filled with the realized total below
-                    n_runs=acct[idx].runs,
-                )
-                timeline[idx].finish_t = finish_t
-                live.remove(t)
-                if start_generation(idx, t, finish_t):
-                    continue  # row stays: the session now generates on it
-                del row_owner[t.row]
-                del acct_by_row[t.row]
-                pool.release(t.row, t.label, finish_t)
-            occupancy.append((round_t, len(live)))
+                # --- completions: extract the row, then generate or recycle
+                with TraceAnnotation("sched.complete", round=stats.n_rounds):
+                    for t in [x for x in live if x.done]:
+                        idx = row_owner[t.row]
+                        finish_t = max(t.clock.fetch_t, t.clock.compute_t)
+                        results[idx] = t.result(
+                            extract_row(caches, t.row),
+                            wall_total_s=0.0,  # the realized total, below
+                            n_runs=acct[idx].runs,
+                        )
+                        timeline[idx].finish_t = finish_t
+                        live.remove(t)
+                        if start_generation(idx, t, finish_t):
+                            continue  # row stays: the session generates on it
+                        del row_owner[t.row]
+                        del acct_by_row[t.row]
+                        pool.release(t.row, t.label, finish_t)
+                occupancy.append((round_t, len(live)))
         jax.block_until_ready(caches.kv_k)
         wall_total = time.perf_counter() - wall0
         assert all(r is not None for r in results)
@@ -1342,8 +1345,6 @@ class ContinuousScheduler:
             occupancy=occupancy,
             n_rows=n_rows,
             wall_total_s=wall_total,
-            wall_decode_s=stats.decode_s,
-            wall_recompute_s=stats.recompute_s,
             n_rounds=stats.n_rounds,
             n_decode_batches=stats.n_decode_batches,
             n_text_batches=stats.n_text_batches,

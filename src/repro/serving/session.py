@@ -125,7 +125,9 @@ compatible records (``SessionResult.stream_result()``), so everything that
 consumes simulator output — SLO accounting, figure scripts — reads session
 output unchanged, and the simulator becomes a cross-check rather than the
 only story.  Virtual time (``ttft_s``) stays simulator-comparable; realized
-host time is reported separately (``wall_*``).
+host time is ``wall_total_s`` (ends in a device sync), and the host work
+inside a step is named by profiler spans (``stream.step``,
+``stream.decide``, ``stream.fetch_wait``) on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -136,6 +138,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import bitstream
 from repro.core import codec as kvcodec
@@ -180,10 +183,8 @@ class SessionResult:
     ``timelines``/``ttft_s`` use the trace-driven virtual clock (fetch) plus
     the simulator's compute charging — directly comparable to
     ``simulate_stream`` output.  ``caches`` is the real materialized serving
-    cache; ``wall_*`` are realized host seconds (decode dispatch is
-    asynchronous, so per-category times are dispatch times and
-    ``wall_total_s`` — measured through a final blocking sync — is the
-    end-to-end truth).
+    cache; ``wall_total_s`` is realized host seconds through a final
+    blocking sync.
     """
 
     timelines: List[ChunkTimeline]
@@ -191,8 +192,6 @@ class SessionResult:
     ttft_s: float
     slo_s: float
     caches: Caches
-    wall_decode_s: float
-    wall_recompute_s: float
     wall_total_s: float
     n_runs: int
     # fault tolerance (ISSUE 6): "ok" or "failed"; a failed load's caches
@@ -626,6 +625,10 @@ class SessionTask:
         only *issues* I/O returns none.  The last chunk also flushes the
         segmenter, so once :attr:`done` every item has been emitted.
         """
+        with TraceAnnotation("stream.step", req=self.label, chunk=self._i):
+            return self._step()
+
+    def _step(self) -> List[object]:
         if self.suspended:
             raise RuntimeError(
                 f"stepping request {self.label!r}: suspended at "
@@ -637,7 +640,9 @@ class SessionTask:
             if policy is None:
                 # legacy path: any fetch failure raises straight through
                 self._pending = None
-                res = handle.result()
+                with TraceAnnotation("stream.fetch_wait", req=self.label,
+                                     chunk=m.chunk_idx, level=config):
+                    res = handle.result()
                 if self.session.validate_blobs:
                     validate_blob(res.blobs[0], m, config)
                 tl = self.clock.account(m, config, nbytes, res, scale)
@@ -651,20 +656,21 @@ class SessionTask:
             return []
         i = self._i
         m = self.metas[i]
-        if policy is not None and (self._banned or self._salvage is not None):
-            try:
-                config, nbytes, scale = self.clock.decide(
-                    self.metas,
-                    i,
-                    exclude=self._banned,
-                    credit=self._credit(m),
-                )
-            except NoFeasibleConfigError as e:
-                return self._fail(e)
-            if config == TEXT and self._banned:
-                self.n_fault_text += 1
-        else:
-            config, nbytes, scale = self.clock.decide(self.metas, i)
+        with TraceAnnotation("stream.decide", req=self.label, chunk=i):
+            if policy is not None and (self._banned or self._salvage is not None):
+                try:
+                    config, nbytes, scale = self.clock.decide(
+                        self.metas,
+                        i,
+                        exclude=self._banned,
+                        credit=self._credit(m),
+                    )
+                except NoFeasibleConfigError as e:
+                    return self._fail(e)
+                if config == TEXT and self._banned:
+                    self.n_fault_text += 1
+            else:
+                config, nbytes, scale = self.clock.decide(self.metas, i)
         if config == TEXT:
             # text is already local — its transfer is modeled, not fetched
             outcome = self.clock.virtual_fetch(nbytes, m.chunk_idx)
@@ -741,7 +747,9 @@ class SessionTask:
         timeout = policy.wall_timeout_s if realtime else None
         mode = self._pending_mode
         try:
-            res = handle.result(timeout=timeout)
+            with TraceAnnotation("stream.fetch_wait", req=self.label,
+                                 chunk=m.chunk_idx, level=config):
+                res = handle.result(timeout=timeout)
         except Exception as e:
             return self._on_fetch_failure(e, handle, m, config, nbytes, scale)
         # §C.1 mid-chunk re-plan (virtual clock only): the fetch ran far
@@ -1094,8 +1102,6 @@ class SessionTask:
         self,
         caches: Caches,
         *,
-        wall_decode_s: float,
-        wall_recompute_s: float,
         wall_total_s: float,
         n_runs: int,
     ) -> SessionResult:
@@ -1111,8 +1117,6 @@ class SessionTask:
             ),
             slo_s=self.session.slo_s,
             caches=caches,
-            wall_decode_s=wall_decode_s,
-            wall_recompute_s=wall_recompute_s,
             wall_total_s=wall_total_s,
             n_runs=n_runs,
             status="failed" if self.failed else "ok",
@@ -1238,8 +1242,6 @@ class ServeSession:
         wall_total = time.perf_counter() - wall0
         return task.result(
             caches,
-            wall_decode_s=state.decode_s,
-            wall_recompute_s=state.recompute_s,
             wall_total_s=wall_total,
             n_runs=state.runs,
         )
@@ -1252,26 +1254,20 @@ class ServeSession:
         """Single-request execution of one work item (the scheduler's
         cross-request batched executors are the N>1 counterpart)."""
         if isinstance(work, TextWork):
-            t0 = time.perf_counter()
             _, caches = self.engine.prefill_extend(
                 jnp.asarray(work.tokens, jnp.int32), caches
             )
-            state.recompute_s += time.perf_counter() - t0
         else:
-            t0 = time.perf_counter()
             kv_run = kvcodec.decode_chunks(
                 work.blobs, work.tables, out_dtype=caches.kv_k.dtype
             )
             caches = self.engine.decode_to_cache(caches, kv_run, work.start)
-            state.decode_s += time.perf_counter() - t0
             state.runs += 1
         return caches
 
 
 @dataclasses.dataclass
 class _ExecState:
-    """Mutable per-run execution state: wall-clock accumulators."""
+    """Mutable per-run execution state: runs decoded."""
 
-    decode_s: float = 0.0
-    recompute_s: float = 0.0
     runs: int = 0

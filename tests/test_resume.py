@@ -343,9 +343,7 @@ def test_preempted_fetch_prefix_survives_suspend_resume(rfix):
     while not task.done:
         for w in task.step():
             caches = sess._execute_one(w, caches, state)
-    res = task.result(caches, wall_decode_s=state.decode_s,
-                      wall_recompute_s=state.recompute_s,
-                      wall_total_s=0.0, n_runs=state.runs)
+    res = task.result(caches, wall_total_s=0.0, n_runs=state.runs)
     assert res.status == "ok" and int(res.caches.length[0]) == T_CTX
     assert res.salvaged_bytes > 0 and res.n_resumes >= 1
     _reconcile(res)
