@@ -15,7 +15,8 @@ chunks.  Four requests then load it through the continuous scheduler on two
 cache rows (rows recycle) and generate 16 tokens each: one pinned to level 0
 (the lossless kernel), two pinned to lossy levels (the dequant kernel), and
 one adaptive request whose recompute is free, so Algorithm 1 recomputes
-TEXT chunks.  Checks: the Pallas kernels equal their jnp twins on one chunk;
+TEXT chunks.  Checks: the Pallas kernels equal their jnp twins on one chunk,
+and the rANS decode equals the scan on it;
 each cache equals the per-chunk ``materialize(fused=False)`` reference (bit
 for bit at level 0, within the session tests' tolerance otherwise); each
 request's scheduler tokens equal ``Engine.generate_with_kv`` on its cache;
@@ -131,10 +132,48 @@ def _greedy(engine, caches, first: int, n: int, batch: int) -> list:
     return out[0].tolist()
 
 
+def rans_check(blob: bytes, ct, passed: list) -> None:
+    """One chunk's anchor and delta streams through ``rans.decode``'s path
+    (the Pallas kernel on a TPU, reading the stacks' laid-out rows) and
+    through the scan, with the tables and table offsets ``decode_chunks``
+    uses: symbols, final states and final word pointers must agree bit for
+    bit."""
+    import jax
+
+    from repro.core import bitstream, gop, rans
+    from repro.core import codec as kvcodec
+
+    parsed = [bitstream.unpack(blob)]
+    h = parsed[0][0]
+    level = int(h["level"])
+    layout = gop.make_layout(int(h["n_tokens"]), int(h["group_size"]))
+    t_idx = np.asarray(ct.table_idx)
+    scan = jax.jit(rans._decode_scan, static_argnames=("precision", "n_sym"))
+    streams = (
+        ("anchors", "a", kvcodec._anchor_stack(ct),
+         ct.anchor.n_tables if level == 0 else 0, layout.n_anchors),
+        ("deltas", "d", kvcodec._delta_decode_stack(ct),
+         kvcodec._delta_table_base(ct, level), layout.n_deltas),
+    )
+    for name, prefix, tab, base, n_sym in streams:
+        w, n, x = kvcodec._stack_streams(parsed, [0], prefix)
+        got = rans._decode_all(w, n, x, t_idx + base, tab, n_sym)
+        want = scan(w, n, x, t_idx + base, tab.freqs, tab.cums, tab.slot2sym,
+                    precision=tab.precision, n_sym=n_sym)
+        same = [np.array_equal(np.asarray(g), np.asarray(v)) for g, v in zip(got, want)]
+        laid_out = rans._row_blocks(t_idx + base, tab.rows) is not None
+        log(f"[kernels] rANS {name} level {level} ({rans.decoder()}, rows laid out "
+            f"once: {laid_out}): {len(w)} lanes x {n_sym} symbols, cap {w.shape[1]}; "
+            f"symbols, state, ptr equal the scan: {same}")
+        check(all(same), f"rANS {rans.decoder()} equals the scan "
+              f"(level {level}, {name})", passed)
+
+
 def kernel_check(ctx, lossy_level: int, passed: list) -> None:
     """Decode chunk 0 at level 0 and at a lossy level with the Pallas
     kernels and with their ``kernels/ref.py`` twins; the outputs must agree
-    (bit for bit at level 0, to f32 rounding at the lossy level)."""
+    (bit for bit at level 0, to f32 rounding at the lossy level).  The rANS
+    decode of the same chunks must equal the scan bit for bit."""
     import jax
 
     from repro.core import codec as kvcodec
@@ -142,6 +181,7 @@ def kernel_check(ctx, lossy_level: int, passed: list) -> None:
     store = ctx.store
     for level in (0, lossy_level):
         blob = store.get_kv(ctx.context_id, 0, level)
+        rans_check(blob, store.tables, passed)
         got = kvcodec.decode_chunks([blob], store.tables, use_pallas=True)
         want = kvcodec.decode_chunks([blob], store.tables, use_pallas=False)
         got, want = np.asarray(jax.block_until_ready(got)), np.asarray(want)

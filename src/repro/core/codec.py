@@ -21,9 +21,9 @@ Tables must be profiled offline per model on calibration KV caches
 
 Fused-path / oracle split (PR 1): the serving hot path is
 :func:`decode_chunks` — a *batched* decode that parses every fetched chunk's
-bitstream once on the host, stacks all lanes into exactly two rANS scans
+bitstream once on the host, stacks all lanes into exactly two rANS decodes
 (anchors for all chunks; deltas for all chunks — mixed lossy levels *and*
-the lossless family share the delta scan via alphabet-padded
+the lossless family share the delta decode via alphabet-padded
 :func:`rans.stack_tables` table stacking), then reconstructs
 every chunk's tokens in a single jitted assemble step that drives the fused
 Pallas kernels in ``kernels/kvquant.py`` (dequant + anchor-broadcast-add +
@@ -32,6 +32,11 @@ f32 ``(L, 2, T, C)`` tensor, no per-chunk host round-trips, no per-chunk
 device dispatch.  :func:`decode_chunk` (singular) is the retained unfused
 reference path — the correctness oracle the fused path is tested against
 (bit-exact at level 0, tolerance-exact at lossy levels).
+
+Each rANS decode runs by platform (:func:`rans.decoder`): on a TPU the
+gather-free Pallas kernel ``kernels/rans_decode.py``, elsewhere the
+``lax.scan`` of table gathers, which is also the kernel's oracle.  The
+``codec.dispatch`` span's ``rans`` stat names the one that ran.
 
 Mirror-image encode batching: :func:`encode_all_levels` symbolizes and
 entropy-codes the (level-invariant) anchors once, and runs all lossy levels'
@@ -104,7 +109,7 @@ class CodecTables(NamedTuple):
     anchor_stack: Optional[rans.CoderTables] = None  # [anchor; ll_anchor]
     lossy_delta_stack: Optional[rans.CoderTables] = None  # deltas lvl 1..n
     # decode-only: all delta sets (lossy levels + lossless) alphabet-padded
-    # into one stack so mixed-level runs need a single delta scan
+    # into one stack so mixed-level runs need a single delta decode
     delta_decode_stack: Optional[rans.CoderTables] = None
 
 
@@ -139,12 +144,14 @@ def ensure_stacks(ct: CodecTables) -> CodecTables:
     Tables built by :func:`profile` already carry them; tables constructed
     by hand or unpickled from pre-stack assets default the fields to None,
     which would otherwise rebuild + re-upload the stacks on every batched
-    coder call.  Long-lived holders (e.g. ``KVStore``) call this once.
+    coder call.  Long-lived holders (e.g. ``KVStore``) call this once.  The
+    decode stacks are laid out for this backend's rANS decoder
+    (:func:`rans.decode_tables`).
     """
     return ct._replace(
-        anchor_stack=_anchor_stack(ct),
+        anchor_stack=rans.decode_tables(_anchor_stack(ct)),
         lossy_delta_stack=_lossy_delta_stack(ct) if ct.deltas else None,
-        delta_decode_stack=_delta_decode_stack(ct),
+        delta_decode_stack=rans.decode_tables(_delta_decode_stack(ct)),
     )
 
 
@@ -369,7 +376,7 @@ def decode_chunk(blob: bytes, ct: CodecTables) -> jnp.ndarray:
 
 
 _CAP_BUCKET = 64  # round padded word caps up: content-dependent stream
-# lengths would otherwise retrace the jitted rANS scan per novel cap
+# lengths would otherwise retrace the jitted rANS decode per novel cap
 
 
 def _stack_streams(
@@ -535,7 +542,7 @@ def decode_chunks(
     """Batched fused decode of several chunk bitstreams (serving hot path).
 
     Parses every blob once on the host, then runs exactly two lane-stacked
-    rANS scans — anchors for all chunks, deltas for all chunks (per-level
+    rANS decodes — anchors for all chunks, deltas for all chunks (per-level
     and lossless tables merged via alphabet-padded
     :func:`rans.stack_tables`) — and a single
     jitted assemble step that applies the fused dequant kernels and emits
@@ -581,8 +588,8 @@ def decode_chunks(
     t_idx_np = np.asarray(ct.table_idx)
     n_ta = ct.anchor.n_tables
 
-    with TraceAnnotation("codec.dispatch", n_chunks=N):
-        # --- anchors: one scan over all chunks (lossy + lossless tables
+    with TraceAnnotation("codec.dispatch", n_chunks=N, rans=rans.decoder()):
+        # --- anchors: one decode over all chunks (lossy + lossless tables
         # stacked)
         aw, an, ax = _stack_streams(parsed, range(N), "a")
         t_idx_a = np.concatenate(
@@ -590,7 +597,7 @@ def decode_chunks(
         )
         a_sym = rans.decode(aw, an, ax, t_idx_a, _anchor_stack(ct), Gmax)
 
-        # --- deltas: ONE scan for all chunks — lossy levels and the
+        # --- deltas: ONE decode for all chunks — lossy levels and the
         # lossless family (different alphabet) share it via alphabet-padded
         # table stacking
         d_max = max(m[3] for m in metas)
@@ -645,7 +652,7 @@ def decode_chunk_runs(
 
     ``runs`` is one entry per request — that request's consecutive bitstream
     chunks (what a single :func:`decode_chunks` call would take).  All runs
-    are flattened into *one* pair of lane-stacked rANS scans and one jitted
+    are flattened into *one* pair of lane-stacked rANS decodes and one jitted
     assemble (``decode_chunks``), so N concurrent requests cost the same
     number of device dispatches as one.  The jit signature is shaped by the
     flattened run geometry (chunk token counts + lossy/lossless split)

@@ -12,9 +12,21 @@ rANS (range asymmetric numeral systems) is in the same entropy-coding family
 as arithmetic coding — both approach the entropy bound; we verify in tests
 that compressed sizes match an exact arithmetic-coding oracle within ~1%.
 rANS is chosen over a bit-level AC port because it is table-driven and
-carry-free: the inner loop is a handful of integer ops + gathers, exactly the
-shape of computation TPU vector units (and XLA:CPU) run well; CUDA-style
-bit/carry manipulation has no TPU analogue.
+carry-free: the inner loop is a handful of integer ops and one table lookup,
+with no CUDA-style bit/carry manipulation, which has no TPU analogue.
+
+Decode by platform (:func:`decoder`): on CPU the scan looks symbols up with
+per-lane gathers, which are cheap there; it is also the oracle.  On a TPU
+those gathers are random reads from hundreds of MB of tables in HBM, so
+:func:`decode` runs the Pallas kernel ``kernels/rans_decode.py`` instead:
+per block of 1024 lanes it keeps the state, the lanes' cumulative-frequency
+rows and their words in VMEM and finds each symbol by compare-count over the
+row, with the same integer updates bit for bit.  A decode stack is laid out
+for its decoder once (:func:`decode_tables`): for the kernel, its rows
+lane-major, which the codec's lanes read in place, and no ``slot2sym``,
+which only the scan reads.  Both run inside the one jitted
+``_decode_impl``, whose program name the benchmark reads.  Encoding is
+offline and stays a scan.
 
 Variant: 32-bit state, 16-bit word renormalization (ryg_rans "rans_word").
 With precision ``k <= 14`` and all frequencies >= 1 (< 2^k), each symbol
@@ -27,13 +39,18 @@ lane.  The decoder reads words in reverse emission order (rANS is LIFO).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["CoderTables", "encode", "decode", "encoded_bytes", "stack_tables"]
+from repro.kernels.rans_decode import BLOCK_LANES, lane_rows, rans_decode_pallas
+
+__all__ = [
+    "CoderTables", "encode", "decode", "decoder", "decode_tables", "encoded_bytes",
+    "stack_tables",
+]
 
 RANS_L = jnp.uint32(1 << 16)  # lower bound of the normalized state interval
 _U32_ONE = jnp.uint32(1)
@@ -44,14 +61,18 @@ class CoderTables(NamedTuple):
 
     freqs: (n_tables, A) uint32, each row sums to 2**precision, all >= 1
     cums:  (n_tables, A + 1) uint32 exclusive prefix sums
-    slot2sym: (n_tables, 2**precision) uint16
+    slot2sym: (n_tables, 2**precision) uint16, or None where the tables are
+        laid out for the kernel (the scan then derives it from ``cums``)
     precision: int (static)
+    rows: the kernel's lane-major layout of ``cums`` (``lane_rows``), or
+        None; see :func:`decode_tables`
     """
 
     freqs: jnp.ndarray
     cums: jnp.ndarray
-    slot2sym: jnp.ndarray
+    slot2sym: Optional[jnp.ndarray]
     precision: int
+    rows: Optional[jnp.ndarray] = None
 
     @property
     def alphabet(self) -> int:
@@ -107,21 +128,25 @@ def _encode_impl(
     return buf, ptr, x
 
 
-@functools.partial(jax.jit, static_argnames=("precision", "n_sym"))
-def _decode_impl(
+def _decode_scan(
     words: jnp.ndarray,  # (n_lanes, cap) uint16
     n_words: jnp.ndarray,  # (n_lanes,) int32
     state: jnp.ndarray,  # (n_lanes,) uint32
     table_idx: jnp.ndarray,  # (n_lanes,) int32
     freqs: jnp.ndarray,
     cums: jnp.ndarray,
-    slot2sym: jnp.ndarray,
+    slot2sym: Optional[jnp.ndarray],
     precision: int,
     n_sym: int,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The decode as a ``lax.scan`` of per-lane table gathers: the CPU path
+    and the oracle of the TPU kernel."""
     n_lanes = words.shape[0]
     A = freqs.shape[-1]
     M = 1 << precision
+    if slot2sym is None:  # tables laid out for the kernel: derive the lookup
+        slots = jnp.arange(M, dtype=jnp.uint32)
+        slot2sym = jax.vmap(lambda c: jnp.searchsorted(c[1:], slots, side="right"))(cums)
     freqs_flat = freqs.reshape(-1)
     cums_flat = cums.reshape(-1)
     s2s_flat = slot2sym.reshape(-1)
@@ -152,6 +177,72 @@ def _decode_impl(
     return syms.T, x, ptr  # symbols (n_lanes, n_sym) in forward order
 
 
+def decoder() -> str:
+    """The decoder :func:`decode` runs on this process's backend: the Pallas
+    kernel on a TPU, the scan elsewhere."""
+    return "kernel" if jax.default_backend() == "tpu" else "scan"
+
+
+_KERNEL_INTERPRET = False  # the kernel runs natively; tests interpret it on CPU
+
+
+@functools.partial(
+    jax.jit, static_argnames=("precision", "n_sym", "kernel", "interpret")
+)
+def _decode_impl(
+    words: jnp.ndarray,  # (n_lanes, cap) uint16
+    n_words: jnp.ndarray,  # (n_lanes,) int32
+    state: jnp.ndarray,  # (n_lanes,) uint32
+    table_idx: jnp.ndarray,  # (n_lanes,) int32
+    freqs: jnp.ndarray,
+    cums: jnp.ndarray,
+    slot2sym: Optional[jnp.ndarray],
+    rows: Optional[jnp.ndarray],  # the tables' kernel layout, or None
+    blocks: Optional[jnp.ndarray],  # each lane block's block of ``rows``
+    *,
+    precision: int,
+    n_sym: int,
+    kernel: bool,
+    interpret: bool,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The scan, or the kernel with the tables' laid-out ``rows``; without
+    them the kernel reads this call's lanes' rows, laid out here."""
+    if not kernel:
+        return _decode_scan(
+            words, n_words, state, table_idx, freqs, cums, slot2sym, precision, n_sym
+        )
+    if rows is None:
+        rows = lane_rows(cums[table_idx], precision)
+        blocks = jnp.arange(rows.shape[1] // 8, dtype=jnp.int32)
+    return rans_decode_pallas(
+        words, n_words, state, rows, blocks, precision=precision, n_sym=n_sym,
+        interpret=interpret,
+    )
+
+
+def _row_blocks(table_idx: np.ndarray, rows: Optional[jnp.ndarray]) -> Optional[np.ndarray]:
+    """Each lane block's block of the tables' ``rows``, where every block's
+    lanes read consecutive tables from a multiple of 1024 on (a decode
+    stack's lanes do: one table per lane, sets of whole blocks); else None."""
+    n = table_idx.shape[0]
+    if rows is None or n == 0:
+        return None
+    start = table_idx - np.arange(n, dtype=np.int32) % BLOCK_LANES
+    blocks = start[::BLOCK_LANES]
+    if (blocks % BLOCK_LANES).any() or (np.repeat(blocks, BLOCK_LANES)[:n] != start).any():
+        return None
+    return blocks // BLOCK_LANES
+
+
+def decode_tables(tables: CoderTables) -> CoderTables:
+    """``tables`` as this backend's decoder reads them, for a decode stack
+    built once: for the kernel, with its lane-major ``rows`` and without
+    ``slot2sym``, which only the scan reads; for the scan, unchanged."""
+    if decoder() != "kernel" or tables.rows is not None:
+        return tables
+    return tables._replace(slot2sym=None, rows=lane_rows(tables.cums, tables.precision))
+
+
 def encode(
     symbols: jnp.ndarray,
     table_idx: jnp.ndarray,
@@ -169,6 +260,29 @@ def encode(
     )
 
 
+def _decode_all(
+    words, n_words, state, table_idx, tables: CoderTables, n_sym: int
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Symbols, final states and final word pointers, by :func:`decoder`."""
+    kernel = decoder() == "kernel"
+    blocks = _row_blocks(np.asarray(table_idx, np.int32), tables.rows) if kernel else None
+    return _decode_impl(
+        jnp.asarray(words),
+        jnp.asarray(n_words, dtype=jnp.int32),
+        jnp.asarray(state),
+        jnp.asarray(table_idx, dtype=jnp.int32),
+        tables.freqs,
+        tables.cums,
+        tables.slot2sym,
+        tables.rows if blocks is not None else None,
+        blocks,
+        precision=tables.precision,
+        n_sym=n_sym,
+        kernel=kernel,
+        interpret=_KERNEL_INTERPRET,
+    )
+
+
 def decode(
     words: jnp.ndarray,
     n_words: jnp.ndarray,
@@ -179,17 +293,7 @@ def decode(
     check: bool = False,
 ) -> jnp.ndarray:
     """Decode ``n_sym`` symbols per lane.  Exact inverse of :func:`encode`."""
-    syms, x, ptr = _decode_impl(
-        jnp.asarray(words),
-        jnp.asarray(n_words, dtype=jnp.int32),
-        jnp.asarray(state),
-        jnp.asarray(table_idx, dtype=jnp.int32),
-        tables.freqs,
-        tables.cums,
-        tables.slot2sym,
-        tables.precision,
-        n_sym,
-    )
+    syms, x, ptr = _decode_all(words, n_words, state, table_idx, tables, n_sym)
     if check:
         x = np.asarray(x)
         ptr = np.asarray(ptr)
@@ -224,7 +328,8 @@ def stack_tables(
     to the widest alphabet.  This is sound for **decoding only**: the
     decoder reads ``freqs[s]``/``cums[s]`` exclusively for symbols ``s``
     produced by ``slot2sym`` (always < the set's true alphabet), so the
-    padding is never touched.  Padded tables must not be used to encode —
+    padding is never touched (nor by the kernel's compare-count: an
+    edge-padded entry equals ``M``).  Padded tables must not be used to encode —
     a padded symbol id would emit a zero-frequency state transition.
     """
     if not tabs:
@@ -257,6 +362,9 @@ def stack_tables(
     return CoderTables(
         freqs=jnp.concatenate([f for f, _ in parts], axis=0),
         cums=jnp.concatenate([c for _, c in parts], axis=0),
-        slot2sym=jnp.concatenate([t.slot2sym for t in tabs], axis=0),
+        # only the scan reads slot2sym; the kernel's platform leaves it out
+        slot2sym=None
+        if decoder() == "kernel" or any(t.slot2sym is None for t in tabs)
+        else jnp.concatenate([t.slot2sym for t in tabs], axis=0),
         precision=precision,
     )

@@ -7,6 +7,16 @@ are smollm-360m's: 32 layers x (K, V) lanes of 5 KV heads x 64 channels,
 256-token chunks in groups of 10, so G = 26 anchor groups per chunk; G = 32
 covers a group count that is a multiple of 8.
 
+The rANS decode program compiles with its kernel for an 8-chunk round of
+256-token chunks (8 x 20480 lanes): the anchors' 26 steps at word cap 64
+over the two 256-symbol sets, and the deltas' 230 steps at cap 192 over the
+five sets padded to 509 symbols, reading the stacks' laid-out rows and, for
+the deltas, rows gathered per call.  Its VMEM does not grow with the chunk:
+it compiles too for the codec's default 1536-token chunks (154 anchors at
+cap 192, 1382 deltas at cap 704) and for chunks of the whole 2048-token
+capacity at the largest caps ``_CAP_BUCKET`` gives there (205 anchors at
+cap 256, 1843 deltas at cap 1856: a lane takes at most one word a symbol).
+
 The topology is described inside a module fixture (one worker loads the
 TPU compiler library and keeps it), and the persistent compilation cache is
 off around these compiles: an entry written for a described chip cannot be
@@ -21,8 +31,9 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import codec as kvcodec
-from repro.core import gop
+from repro.core import gop, rans
 from repro.kernels.kvquant import kv_dequant_tokens_pallas, kv_lossless_tokens_pallas
+from repro.kernels.rans_decode import BLOCK_LANES
 
 L, C, GROUP, QMAX = 32, 5 * 64, 10, 127
 CHUNK = 256
@@ -94,3 +105,45 @@ def test_assemble_chunks_runs_the_kernels(one_chip):
         block_groups=8,
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize(
+    "chunks, n_sets, alphabet, n_sym, cap, laid_out",
+    [
+        (8, 2, 256, 26, 64, True),
+        (8, 5, 509, 230, 192, True),
+        (8, 5, 509, 230, 192, False),
+        (2, 2, 256, 154, 192, True),
+        (2, 5, 509, 1382, 704, True),
+        (1, 2, 256, 205, 256, True),
+        (1, 5, 509, 1843, 1856, True),
+    ],
+    ids=[
+        "anchors-256", "deltas-256", "deltas-256-gathered", "anchors-1536",
+        "deltas-1536", "anchors-2048", "deltas-2048",
+    ],
+)
+def test_rans_decode_kernel_compiles(one_chip, chunks, n_sets, alphabet, n_sym, cap, laid_out):
+    lanes, n_tables = chunks * L * 2 * C, n_sets * L * 2 * C
+    n_entries = -(-(alphabet + 1) // 8) * 8
+    rows = (
+        _spec(one_chip, (n_entries, -(-n_tables // BLOCK_LANES) * 8, 128), jnp.int32)
+        if laid_out else None
+    )
+    blocks = _spec(one_chip, (lanes // BLOCK_LANES,), jnp.int32) if laid_out else None
+    compiled = rans._decode_impl.lower(
+        _spec(one_chip, (lanes, cap), jnp.uint16),
+        _spec(one_chip, (lanes,), jnp.int32),
+        _spec(one_chip, (lanes,), jnp.uint32),
+        _spec(one_chip, (lanes,), jnp.int32),
+        _spec(one_chip, (n_tables, alphabet), jnp.uint32),
+        _spec(one_chip, (n_tables, alphabet + 1), jnp.uint32),
+        None,
+        rows,
+        blocks,
+        precision=11,
+        n_sym=n_sym,
+        kernel=True,
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
