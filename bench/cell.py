@@ -2,7 +2,14 @@
 
 A cell is a configuration (``bench/configs/<config>.json``) under a traffic
 mix (``bench/traffic/<mix>.json``), sized by its cell file
-(``bench/cells/<workload>.json``: clients, documents, limits).
+(``bench/cells/<workload>.json``: clients, documents, limits).  Everything
+architecture-specific comes from the reference module that the
+configuration's ``reference`` key names (``bench/reference/__init__.py``):
+the weights, the program's architecture fields it implies, and the check.
+The program's architecture is its ``registry`` entry with those fields put
+in, so that a cut to the chip's share (layers kept, experts held, a
+vocabulary slice) is written once, in the configuration's own keys listed
+in ``reduced``, and the program and the reference both read it.
 
 Set-up makes the weights from the seed on the device, prefills the
 documents, profiles the codec on a calibration sample of the first document,
@@ -30,6 +37,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from bench import reference
 from bench import traffic as traffic_mod
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -207,7 +215,9 @@ class Cell:
         self.name = workload
         self.seed = int(seed)
         self.log = log
+        self.config_file = os.path.join("bench", "configs", f"{entry['config']}.json")
         self.cfg = dict(load_json("configs", f"{entry['config']}.json"), **over.get("config", {}))
+        self.ref = reference.load(self.cfg["reference"], self.config_file)
         self.mix = dict(traffic_mod.load(entry["traffic"]), **over.get("traffic", {}))
         self.cell = dict(load_json("cells", f"{workload}.json"), **over.get("cell", {}))
         self.chips = int(entry["chips"])
@@ -228,17 +238,15 @@ class Cell:
         import jax
         import jax.numpy as jnp
 
-        from bench.reference import dense_lm as ref
         from repro.configs import registry
         from repro.core import codec as kvcodec
         from repro.serving.engine import Engine
         from repro.serving.kv_layout import caches_to_codec_kv
         from repro.streaming import CacheGenStreamer, KVStore
 
-        cfg, cell, mix = self.cfg, self.cell, self.mix
+        cfg, cell, mix, ref = self.cfg, self.cell, self.mix, self.ref
         t = time.perf_counter()
-        self.arch = registry.get(cfg["registry"])
-        _check_arch(self.arch, cfg)
+        self.arch = dataclasses.replace(registry.get(cfg["registry"]), **ref.program_fields(cfg))
         init = jax.jit(lambda key: ref.init_params(cfg, key))
         self.params = jax.block_until_ready(init(ref.make_key(self.seed)))
         served = self.params
@@ -295,6 +303,7 @@ class Cell:
         distinct padded stream width among the stored chunks at the levels
         it served, so that no run of the window compiles."""
         import jax
+        import jax.numpy as jnp
 
         from repro.core import bitstream
 
@@ -312,17 +321,20 @@ class Cell:
                     by_width.setdefault(key, blob)
         rows = int(self.cell["clients"])
         caches = self.engine.empty_caches(rows)
+        # the served path decodes into the cache's own dtype
+        dtype = next(x.dtype for x in jax.tree_util.tree_leaves(caches)
+                     if jnp.issubdtype(x.dtype, jnp.floating))
         for n in sorted(sizes):
             for blob in by_width.values():
                 kv, spans = self.probe.orig_decode(
                     [[blob]] * n, self.store.tables,
-                    out_dtype=caches.kv_k.dtype, run_tokens=[ct] * n,
+                    out_dtype=dtype, run_tokens=[ct] * n,
                 )
                 caches = self.engine.insert_runs(
                     caches, kv, rows=list(range(n)), starts=[0] * n,
                     run_tokens=[m for _, m in spans],
                 )
-        jax.block_until_ready(caches.kv_k)
+        jax.block_until_ready(caches)
         self.probe.decode_calls.clear()
         self.probe.steps.clear()
 
@@ -514,8 +526,6 @@ class Cell:
         ``control_gap`` and ``control_share`` (1).  ``extra_sides`` adds
         further ``name: (weights dtype, numerics)`` runs of the reference,
         and each one's ``<name>_share``, its mean gap over the control's."""
-        from bench.reference import dense_lm as ref
-
         sample = self.sample()
         if not sample:
             inf = float("inf")
@@ -524,7 +534,7 @@ class Cell:
         c = self.cell["control"]
         sides = dict(control=(c["weights"], (c["operands"], c["stored"])), **(extra_sides or {}))
         max_tokens = int(self.mix["output_tokens"][1]) + 1
-        gaps = ref.served_gaps(
+        gaps = self.ref.served_gaps(
             self.params, self.cfg, sample, self.codec, self.calib_tokens,
             max_tokens=max_tokens, sides=sides,
         )
@@ -536,20 +546,4 @@ class Cell:
         gaps["n_requests"] = len(sample)
         gaps["n_tokens"] = sum(len(r["tokens"]) for r in sample)
         return gaps
-
-
-def _check_arch(arch, cfg: dict) -> None:
-    """The program's registry entry must have the configuration's sizes."""
-    d = cfg["hidden_size"]
-    want = dict(
-        n_layers=cfg["num_hidden_layers"], d_model=d,
-        n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
-        d_head=cfg.get("head_dim", d // cfg["num_attention_heads"]),
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        tie_embeddings=cfg["tie_word_embeddings"], norm=cfg["norm"],
-        rope_theta=float(cfg["rope_theta"]),
-    )
-    got = {k: getattr(arch, k) for k in want}
-    if got != want:
-        raise SystemExit(f"registry {cfg['registry']!r} has {got}, configuration has {want}")
 

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, Iterable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,14 +37,29 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _dims(cfg: dict) -> dict:
+def _sizes(cfg: dict) -> dict:
     d = cfg["hidden_size"]
     hq = cfg["num_attention_heads"]
     return dict(
         L=cfg["num_hidden_layers"], d=d, hq=hq, hkv=cfg["num_key_value_heads"],
         dh=cfg.get("head_dim", d // hq), ff=cfg["intermediate_size"],
-        V=cfg["vocab_size"], theta=float(cfg["rope_theta"]),
-        norm=cfg["norm"], eps=float(cfg["norm_eps"]),
+        V=cfg["vocab_size"],
+    )
+
+
+def _dims(cfg: dict) -> dict:
+    return dict(_sizes(cfg), theta=float(cfg["rope_theta"]), norm=cfg["norm"],
+                eps=float(cfg["norm_eps"]))
+
+
+def program_fields(cfg: dict) -> dict:
+    """The program's ``ArchConfig`` fields that the configuration implies."""
+    m = _sizes(cfg)
+    return dict(
+        n_layers=m["L"], d_model=m["d"], n_heads=m["hq"], n_kv_heads=m["hkv"],
+        d_head=m["dh"], d_ff=m["ff"], vocab_size=m["V"],
+        tie_embeddings=cfg["tie_word_embeddings"], norm=cfg["norm"],
+        rope_theta=float(cfg["rope_theta"]),
     )
 
 
@@ -379,3 +394,55 @@ def served_gaps(params, cfg: dict, requests: List[dict], codec: dict,
         out[name + "gap_sq_mean"] = float(np.mean(g * g))
         out[name + "flip_share"] = float(np.mean(g > 0))
     return out
+
+
+# ---------------------------------------------------------------------------
+# The least work of the served path (``bench/costs.py``)
+# ---------------------------------------------------------------------------
+
+BF16 = 2
+
+
+def n_params(cfg: dict) -> int:
+    """Weights of the served model (tied embedding counted once; norm gains
+    included)."""
+    m = _sizes(cfg)
+    per_layer = (
+        m["d"] * m["hq"] * m["dh"] * 2  # wq, wo
+        + m["d"] * m["hkv"] * m["dh"] * 2  # wk, wv
+        + 3 * m["d"] * m["ff"]  # gate, up, down
+    )
+    gains = (2 * m["L"] + 1) * m["d"] if cfg["norm"] == "rmsnorm" else 0
+    return m["L"] * per_layer + m["V"] * m["d"] + gains
+
+
+def kv_bytes_per_token(cfg: dict) -> int:
+    """bf16 K and V of one token over all layers."""
+    m = _sizes(cfg)
+    return m["L"] * 2 * m["hkv"] * m["dh"] * BF16
+
+
+def decode_step(cfg: dict, lengths: Iterable[int]) -> dict:
+    """One decode step for the active rows, each holding ``length`` tokens
+    before the step: the least FLOPs and HBM bytes.
+
+    FLOPs: 2 per weight per row for the layer matmuls and the output head,
+    plus 2 x 2 per (query head, head dim, attended position) per layer.
+    Bytes: every weight read once, each active row's cached K and V read
+    once, its new token's K and V written once.
+    """
+    m = _sizes(cfg)
+    lengths = [int(n) for n in lengths]
+    rows = len(lengths)
+    matmul = n_params(cfg) - ((2 * m["L"] + 1) * m["d"] if cfg["norm"] == "rmsnorm" else 0)
+    attended = sum(n + 1 for n in lengths)
+    flops = 2 * matmul * rows + 4 * m["hq"] * m["dh"] * attended * m["L"]
+    per_tok = kv_bytes_per_token(cfg)
+    nbytes = n_params(cfg) * BF16 + per_tok * sum(lengths) + per_tok * rows
+    return dict(flops=float(flops), bytes=float(nbytes))
+
+
+def token_block(cfg: dict):
+    """(layer, K/V) rows and channels of one stored chunk."""
+    m = _sizes(cfg)
+    return m["L"] * 2, m["hkv"] * m["dh"]

@@ -2,11 +2,12 @@
 import pytest
 
 from bench import costs
+from bench.reference import dense_lm
 
 CFG = {  # smollm-360m's shape
     "num_hidden_layers": 32, "hidden_size": 960, "num_attention_heads": 15,
     "num_key_value_heads": 5, "head_dim": 64, "intermediate_size": 2560,
-    "vocab_size": 49152, "norm": "rmsnorm",
+    "vocab_size": 49152, "norm": "rmsnorm", "reference": "dense_lm",
 }
 CODEC = {"group_size": 10}
 PEAK = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
@@ -16,11 +17,11 @@ def test_params_by_hand():
     per_layer = 960 * 960 * 2 + 960 * 320 * 2 + 3 * 960 * 2560
     assert per_layer == 9_830_400
     want = 32 * per_layer + 49152 * 960 + 65 * 960
-    assert costs.n_params(CFG) == want == 361_821_120
+    assert dense_lm.n_params(CFG) == want == 361_821_120
 
 
 def test_kv_bytes_per_token():
-    assert costs.kv_bytes_per_token(CFG) == 32 * 2 * 320 * 2 == 40_960
+    assert dense_lm.kv_bytes_per_token(CFG) == 32 * 2 * 320 * 2 == 40_960
 
 
 def test_decode_step_by_hand():
