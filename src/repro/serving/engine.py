@@ -43,6 +43,18 @@ from repro.serving import kv_layout
 __all__ = ["Engine"]
 
 
+def _last_logits(logits: jax.Array) -> jax.Array:
+    """The last position's logits as float32, (B, T, V) -> (B, V)."""
+    return logits[:, -1].astype(jnp.float32)
+
+
+def _greedy_pick(logits: jax.Array) -> jax.Array:
+    """Greedy ids of the last position, (B, T, V) -> (B,) int32.  The f32
+    cast is exact and monotone and ties take the first index, so the ids
+    equal ``np.argmax`` over a float32 host copy of the same row."""
+    return jnp.argmax(_last_logits(logits), axis=-1).astype(jnp.int32)
+
+
 class Engine:
     # Shard-aware row addressing: the base engine is a single shard, so the
     # global row space and the local one coincide.  The mesh-sharded
@@ -64,6 +76,13 @@ class Engine:
             functools.partial(lm.prefill, cfg), static_argnames=("pad_to",)
         )
         self._decode = jax.jit(functools.partial(lm.decode_step, cfg))
+        # What a generation step copies to the host: the greedy ids alone,
+        # or (for a step that samples) the last position's logits, each as
+        # one program rather than an eager slice chain.  Plain jits:
+        # row-sharded logits (ShardedEngine) stay sharded in, and the host
+        # copy gathers them.
+        self.greedy_pick = jax.jit(_greedy_pick)
+        self.last_logits = jax.jit(_last_logits)
         if cfg.family in ("dense", "moe", "vlm"):
             self._extend = jax.jit(functools.partial(lm.prefill_extend, cfg))
             self._extend_rows = jax.jit(

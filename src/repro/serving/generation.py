@@ -122,6 +122,12 @@ class GenerationTask:
         """Row tokens realized so far: context prefix + emitted output."""
         return self.context_tokens + len(self.tokens_out)
 
+    @property
+    def greedy(self) -> bool:
+        """Whether this task decodes by argmax (no sampling seed): the
+        scheduler then takes its next token from the device pick."""
+        return self._rng is None
+
     def next_token(self, logits_row: np.ndarray) -> int:
         """Pick the next token from this row's last-position logits.
 

@@ -254,6 +254,7 @@ class _BatchStats:
     n_text_batches: int = 0
     n_runs: int = 0
     n_gen_steps: int = 0
+    n_device_picks: int = 0  # generation steps whose greedy ids came from the device
     n_gen_tokens: int = 0
 
 
@@ -818,7 +819,9 @@ class ContinuousResult:
     per-request ``timeline`` entries.  ``wall_gen_s`` is realized host
     seconds inside stacked ``decode_step_rows`` dispatches (per-step token
     sync included), so ``n_gen_tokens / wall_gen_s`` is the engine's
-    realized aggregate generation throughput.
+    realized aggregate generation throughput.  ``n_device_picks`` counts the
+    generation steps with no sampled row, whose greedy ids were picked on
+    the device and copied alone.
     """
 
     sessions: List[SessionResult]
@@ -835,6 +838,7 @@ class ContinuousResult:
     gen_occupancy: List[Tuple[float, int]] = dataclasses.field(default_factory=list)
     wall_gen_s: float = 0.0
     n_gen_steps: int = 0
+    n_device_picks: int = 0
     n_gen_tokens: int = 0
 
     @property
@@ -1160,9 +1164,17 @@ class ContinuousScheduler:
             logits, caches = self.engine.decode_step_rows(
                 jnp.asarray(tokens), caches, jnp.asarray(active)
             )
-            # host sync per step: the sampled tokens are the next inputs
-            with TraceAnnotation("sched.logits_sync"):
-                last = np.asarray(logits[:, -1], np.float32)
+            # host sync per step: the picked tokens are the next inputs.  An
+            # all-greedy step copies the device's (B,) ids; a step that holds
+            # a sampled row copies the last position's logits and every row
+            # picks on the host.
+            on_device = all(g.greedy for g in part)
+            with TraceAnnotation("sched.logits_sync",
+                                 pick="device" if on_device else "host"):
+                if on_device:
+                    ids = np.asarray(self.engine.greedy_pick(logits))
+                else:
+                    last = np.asarray(self.engine.last_logits(logits))
             t_sync = time.perf_counter()
             dt = t_sync - t0
             m = len(part)
@@ -1178,10 +1190,12 @@ class ContinuousScheduler:
             end_t = step_t + self.gen_step_s * self.contention.gen_factor(width)
             stats.gen_s += dt
             stats.n_gen_steps += 1
+            stats.n_device_picks += on_device
             stats.n_gen_tokens += m
             gen_occupancy.append((step_t, m))
             for g in part:
-                g.record(g.next_token(last[g.row]), end_t)
+                tok = int(ids[g.row]) if on_device else g.next_token(last[g.row])
+                g.record(tok, end_t)
                 timeline[g.index].token_wall.append(t_sync)
             gen_busy_t = end_t
             for g in [x for x in part if x.done]:
@@ -1354,5 +1368,6 @@ class ContinuousScheduler:
             gen_occupancy=gen_occupancy,
             wall_gen_s=stats.gen_s,
             n_gen_steps=stats.n_gen_steps,
+            n_device_picks=stats.n_device_picks,
             n_gen_tokens=stats.n_gen_tokens,
         )

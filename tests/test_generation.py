@@ -24,6 +24,13 @@ Invariants (ISSUE 9):
   * cost-aware victim selection — ``_select_victim`` picks the
     least-realized-work candidate under ``least_work`` and the latest
     fetch-end straggler (first-wins ties) under the default policy;
+  * greedy pick — ``Engine.greedy_pick``'s device ids equal ``np.argmax``
+    of a float32 host copy, on random logits, on planted ties (the first
+    index wins) and on row-sharded logits of a two-device ``ShardedEngine``;
+    an all-greedy scheduler run picks on the device at every step and serves
+    the tokens of the host-pick oracle, and a run that mixes greedy and
+    seeded-sampling requests copies logits only on the steps that hold a
+    sampled row, with every token as the host path gives it;
   * calibration — ``stacked_decode_step`` parses into gen contention
     factors and ``ContentionModel.gen_factor`` interpolates/falls back;
   * gen-SLO (ISSUE 10) — realized TPOT over ``GenerationSpec.gen_slo_s``
@@ -33,6 +40,9 @@ Invariants (ISSUE 9):
     with token-exact resumption.
 """
 import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import jax
@@ -57,6 +67,8 @@ from repro.streaming.pipeline import ContentionModel
 T_CTX = 100
 CHUNK = 20  # 5 chunks
 GEN = 8
+VOCAB = 49152  # smollm-360m's: the width the served path's pick reads
+_REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 IDEAL = ContentionModel({1: 1.0, 2: 1.0})  # factor-1 at any N
 
@@ -124,6 +136,22 @@ def _oracle_tokens(gfix, caches, first, n):
     return out[0].tolist()
 
 
+def _host_pick_tokens(gfix, caches, first, n, task=None):
+    """Host-pick reference on the request's loaded cache: one-row
+    ``decode_step_rows`` steps, a float32 host copy of the last position's
+    logits, then ``np.argmax`` (or ``task``'s seeded sample)."""
+    tok = np.array([[first]], np.int32)
+    out = []
+    for _ in range(n):
+        logits, caches = gfix["eng"].decode_step_rows(
+            jnp.asarray(tok), caches, jnp.ones(1, bool)
+        )
+        row = np.asarray(logits[:, -1], np.float32)[0]
+        tok[0, 0] = int(np.argmax(row)) if task is None else task.next_token(row)
+        out.append(int(tok[0, 0]))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # engine: decode_step_rows vs the greedy oracle
 # ---------------------------------------------------------------------------
@@ -173,6 +201,89 @@ def test_decode_step_rows_validates_shapes(gfix):
         eng.decode_step_rows(
             jnp.zeros((2, 1), jnp.int32), caches, jnp.ones(3, bool)
         )
+
+
+# ---------------------------------------------------------------------------
+# engine: the greedy pick on the device
+# ---------------------------------------------------------------------------
+
+
+def _pick_logits(tied: bool) -> np.ndarray:
+    """(8, 1, VOCAB) bfloat16 values held as float32.  ``tied`` plants the
+    row's maximum at three indices (row 0 at both ends of the vocabulary)."""
+    rng = np.random.default_rng(11)
+    x = rng.standard_normal((8, 1, VOCAB)).astype(jnp.bfloat16).astype(np.float32)
+    if tied:
+        for r in range(8):
+            at = [0, 77, VOCAB - 1] if r == 0 else rng.choice(VOCAB, 3, replace=False)
+            x[r, 0, at] = 16.0
+    return x
+
+
+_PICK_ON_TWO_DEVICES = r"""
+import json, sys
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.configs import registry
+from repro.launch.mesh import make_serving_mesh
+from repro.models import build
+from repro.serving.mesh_engine import ShardedEngine
+
+cfg = registry.get("smollm-360m").tiny()
+se = ShardedEngine(cfg, build(cfg).init_params(jax.random.PRNGKey(0)),
+                   cache_capacity=32, mesh=make_serving_mesh(2))
+given = jax.device_put(jnp.asarray(np.load(sys.argv[1]), jnp.bfloat16),
+                       NamedSharding(se.mesh, P("data")))
+toks = np.random.default_rng(5).integers(0, cfg.vocab_size, (4, 1)).astype(np.int32)
+step, _ = se.decode_step_rows(jnp.asarray(toks), se.empty_caches(4), np.ones(4, bool))
+print(json.dumps(dict(
+    given=np.asarray(se.greedy_pick(given)).tolist(),
+    step=np.asarray(se.greedy_pick(step)).tolist(),
+    step_host=np.argmax(np.asarray(step[:, -1], np.float32), -1).tolist(),
+    step_devices=len(step.sharding.device_set),
+)))
+"""
+
+
+def _pick_on_two_devices(tmp_path, logits: np.ndarray) -> dict:
+    """``ShardedEngine.greedy_pick`` over two forced host devices, in a
+    child process (this one keeps its single device): on ``logits`` put
+    row-sharded, and on a sharded ``decode_step_rows`` step's own logits."""
+    path = tmp_path / "logits.npy"
+    np.save(path, logits)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(_REPO, "src"), env.get("PYTHONPATH", "")])
+    out = subprocess.run(
+        [sys.executable, "-c", _PICK_ON_TWO_DEVICES, str(path)], env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "sharded2"])
+def test_greedy_pick_equals_host_argmax(gfix, tmp_path, case):
+    """The device pick equals ``np.argmax`` of the float32 host copy it
+    replaces, bit for bit: on random bfloat16 logits, on planted ties (the
+    first index wins), and on a two-device ShardedEngine's row-sharded
+    logits, given and of its own stacked step."""
+    logits = _pick_logits(tied=case != "random")
+    want = np.argmax(logits[:, -1], axis=-1)
+    if case == "sharded2":
+        out = _pick_on_two_devices(tmp_path, logits)
+        assert out["step_devices"] == 2
+        assert out["step"] == out["step_host"]
+        got = np.asarray(out["given"])
+    else:
+        ids = gfix["eng"].greedy_pick(jnp.asarray(logits, jnp.bfloat16))
+        assert ids.dtype == jnp.int32 and ids.shape == (8,)
+        got = np.asarray(ids)
+    np.testing.assert_array_equal(got, want)
+    if case != "random":
+        assert got[0] == 0
+        assert all(logits[r, 0, got[r]] == 16.0 for r in range(8))
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +389,53 @@ def test_mixed_wave_stacks_generation_and_is_deterministic(gfix):
         want = _oracle_tokens(gfix, a.sessions[i].caches, first, spec)
         assert a.timeline[i].tokens_out == want, f"req {i}"
     assert a.timeline[3].tokens_out == []
+
+
+def test_greedy_run_picks_on_device_and_matches_host_pick(gfix):
+    """Three staggered greedy requests on two rows: every generation step
+    picks on the device, ready rows stack, and each request serves exactly
+    the host-pick oracle's tokens."""
+    u, first = gfix["u"], gfix["first"]
+    n_out = [12, 10, 8]
+    out = ContinuousScheduler(
+        gfix["eng"], rows=2, contention=IDEAL, gen_step_s=0.02,
+    ).run(_requests(
+        gfix, [BandwidthTrace.constant(k * u) for k in (3, 2.5, 2)],
+        sess_kw=[dict(fixed_level=0)] * 3, arrivals=[0.0, 0.02, 0.35],
+        specs=[GenerationSpec(n, first) for n in n_out],
+    ))
+    assert out.n_device_picks == out.n_gen_steps > 0
+    assert max(n for _, n in out.gen_occupancy) == 2
+    for i, n in enumerate(n_out):
+        want = _host_pick_tokens(gfix, out.sessions[i].caches, first, n)
+        assert out.timeline[i].tokens_out == want, f"req {i}"
+
+
+def test_sampled_rows_take_the_host_pick_with_unchanged_tokens(gfix):
+    """A greedy request and a seeded-sampling one generating side by side:
+    exactly the steps that hold the sampled row copy logits to the host
+    (some of them stacked with the greedy row), every other step picks on
+    the device, and both requests serve the host path's tokens."""
+    u, first = gfix["u"], gfix["first"]
+    specs = [GenerationSpec(12, first), GenerationSpec(4, first, sample_seed=123)]
+    out = ContinuousScheduler(
+        gfix["eng"], rows=2, contention=IDEAL, gen_step_s=0.02,
+    ).run(_requests(
+        gfix, [BandwidthTrace.constant(3 * u), BandwidthTrace.constant(2.5 * u)],
+        sess_kw=[dict(fixed_level=0)] * 2, arrivals=[0.0, 0.02], specs=specs,
+    ))
+    greedy, sampled = out.timeline
+    host_steps = set(sampled.token_ts)  # one emission instant per step
+    assert out.n_gen_steps - out.n_device_picks == len(host_steps) == 4
+    assert out.n_device_picks > 0
+    assert host_steps & set(greedy.token_ts)  # a host step stacked both rows
+    assert greedy.tokens_out == _host_pick_tokens(
+        gfix, out.sessions[0].caches, first, 12)
+    task = GenerationTask(specs[1], index=1, label="req1:ctx", row=0,
+                          start_t=0.0, context_tokens=T_CTX,
+                          capacity=gfix["eng"].capacity)
+    assert sampled.tokens_out == _host_pick_tokens(
+        gfix, out.sessions[1].caches, first, 4, task=task)
 
 
 def test_generation_charges_contention(gfix):

@@ -5,8 +5,9 @@
   with ``ProfileData``: every span the program names appears, they nest as
   designed (``sched.run`` > ``sched.round`` > ``stream.step`` >
   ``stream.fetch_wait``; ``sched.gen_step`` > ``sched.logits_sync``), the
-  ``stream.*`` spans carry the request's label, and the span counts equal
-  the scheduler's own round and step counts;
+  ``stream.*`` spans carry the request's label, the span counts equal
+  the scheduler's own round and step counts, and each ``sched.logits_sync``
+  of the all-greedy run carries ``pick=device``;
 * counters — on the benchmark's tiny cell, the chunks decoded at each level
   (read from the sessions' realized ``configs``) equal what the benchmark's
   probe counts from outside, and each request's ``token_wall`` has one
@@ -157,6 +158,15 @@ def test_span_counts_equal_scheduler_counts(traced):
     n_text = sum(1 for s in out.sessions for c in s.configs if c == TEXT)
     assert sum(ev["stats"]["tokens"] for ev in _named(events, "engine.recompute")) == \
         n_text * CHUNK
+
+
+def test_logits_sync_picks_on_the_device(traced):
+    """Every request is greedy, so each step's sync copies the device's ids:
+    every ``sched.logits_sync`` span says so, as does the scheduler's count."""
+    out, events = traced
+    syncs = _named(events, "sched.logits_sync")
+    assert syncs and all(ev["stats"]["pick"] == "device" for ev in syncs)
+    assert out.n_device_picks == out.n_gen_steps == len(syncs)
 
 
 def _decoded(out):
